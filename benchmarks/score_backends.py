@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import util
 from repro.configs.base import get_arch
 from repro.core import score_backend as sb
 from repro.core.score_backend import ScoreWeights
@@ -109,6 +110,7 @@ def run(report):
 
 
 def main():
+    util.enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--json", default="BENCH_scores.json")
     args = ap.parse_args()

@@ -42,6 +42,7 @@ import time
 import numpy as np
 
 from benchmarks.serving_load import _model
+from repro import util
 from repro.serving.engine import Engine, Request
 from repro.serving.frontend import AsyncEngine, FIFOScheduler, SLOScheduler
 
@@ -299,6 +300,7 @@ def run(report):
 
 
 def main():
+    util.enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--json", default="BENCH_async.json")
     args = ap.parse_args()

@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import util
 from repro.configs.base import get_arch, reduced
 from repro.models.model import build_model
 from repro.serving import kvcache
@@ -309,6 +310,7 @@ def run(report):
 
 
 def main():
+    util.enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--json", default="BENCH_serving.json")
     args = ap.parse_args()

@@ -55,6 +55,7 @@ import time
 import jax
 import numpy as np
 
+from repro import util
 from repro.configs.base import get_arch, reduced
 from repro.launch.mesh import parse_mesh
 from repro.models.model import build_model
@@ -299,7 +300,7 @@ def run(report):
     import subprocess
     import sys
     report.section("Replica router: 2-replica scaling + disaggregation")
-    env = dict(os.environ,
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=4")
     env.setdefault("PYTHONPATH", "src")
     r = subprocess.run([sys.executable, "-m", "benchmarks.serving_router"],
@@ -313,6 +314,7 @@ def run(report):
 
 
 def main():
+    util.enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--json", default="BENCH_router.json")
     args = ap.parse_args()

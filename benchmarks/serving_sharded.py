@@ -48,6 +48,7 @@ import warnings
 import jax
 import numpy as np
 
+from repro import util
 from repro.configs.base import get_arch, reduced
 from repro.launch.mesh import make_mesh
 from repro.models.model import build_model
@@ -185,7 +186,7 @@ def run(report):
     import subprocess
     import sys
     report.section("Sharded serving: 1-way vs 4-way tensor parallelism")
-    env = dict(os.environ,
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS=f"--xla_force_host_platform_device_count={TP}")
     env.setdefault("PYTHONPATH", "src")
     r = subprocess.run([sys.executable, "-m", "benchmarks.serving_sharded"],
@@ -199,6 +200,7 @@ def run(report):
 
 
 def main():
+    util.enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--json", default="BENCH_sharded.json")
     args = ap.parse_args()

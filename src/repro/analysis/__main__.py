@@ -58,7 +58,7 @@ def _run_kernelcheck() -> bool:
 def _run_invariants() -> bool:
     from repro.analysis import invariants
     n = invariants.MESH_SHAPE[0] * invariants.MESH_SHAPE[1]
-    env = dict(os.environ)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")     # forced CPU devices
     env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count={n} "
                         + env.get("XLA_FLAGS", "")).strip()
     env.setdefault("PYTHONPATH", os.path.dirname(

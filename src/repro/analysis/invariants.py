@@ -62,34 +62,44 @@ HLO_HOST_MARKERS = ("xla_python_cpu_callback", "xla_ffi_python",
 
 # Pinned collective signatures per (layout, quantization) combo,
 # measured on the 1x4 (data, model) mesh over the reduced 2-layer
-# config. A diff is a structural regression (or a deliberate dataflow
-# change: update the table and DESIGN.md §11 together).
+# config with jax 0.9, lowered under the installed mesh exactly as the
+# engine traces its tick. A diff is a structural regression (or a
+# deliberate dataflow change: update the table and DESIGN.md §11
+# together).
 #
 # EXPECTED_LAYER: one attention layer (attention_decode_paged + wo).
-# kv layouts realize the one-TP-collective claim literally: the single
-# all-reduce is the wo output combine; K/V pool rows are head-sharded
-# so scores/values need no communication. X-cache layouts shard pool
-# rows over D, so every X-consuming contraction (score fold, S·X^T
-# value recompute, wo) combines partial sums — raw inputs move, folded
-# weights stay put, exactly the paper's dataflow; the count is pinned
-# so it can only change deliberately.
+# kv layouts realize the one-TP-collective claim literally: the paged
+# kernel runs per device under shard_map on its head-slice of the pool,
+# and the single all-reduce is the wo output combine. X-cache layouts
+# shard pool rows over D, so the X-consuming contractions (score fold,
+# per-row requant max, S·X^T value recompute, wo) combine partial sums
+# — raw inputs move, folded weights stay put. The three all-to-alls sit
+# at the [X 1] bias augmentation (concatenate): jax 0.9's partitioner
+# keeps the D+1 = 129-wide rows sharded by padding them to 132 and
+# re-slicing the 32-column shards into 33-column ones, where jax 0.4
+# replicated the operand (one more all-reduce). This path is the
+# GSPMD-partitioned jnp stream, which only CPU meshes run: a TPU mesh
+# refuses X-stream plans (serving.engine.check_mesh_plan).
 EXPECTED_LAYER = {
     "kv-float": {"all-reduce": 1},
     "kv-int8": {"all-reduce": 1},
-    "x-int8": {"all-reduce": 4, "all-gather": 2},
+    "x-int8": {"all-reduce": 3, "all-gather": 2, "all-to-all": 3},
 }
 
 # EXPECTED_TICK: the full decode_paged tick. "body" ops sit inside the
 # layer scan (execute per layer); "outer" ops run once per tick (the
 # unembed combine + logits replication; int8-x adds the embed-side
-# quantization combines).
+# quantization combines and the loop-entry reshards of the two [X 1]
+# augmentations, hoisted out of the scan as all-to-alls).
 EXPECTED_TICK = {
     "kv-float": {"body": {"all-reduce": 2},      # wo combine + mlp down
                  "outer": {"all-reduce": 1, "all-gather": 1}},
     "kv-int8": {"body": {"all-reduce": 2},
                 "outer": {"all-reduce": 1, "all-gather": 1}},
-    "x-int8": {"body": {"all-reduce": 10, "all-gather": 4},
-               "outer": {"all-reduce": 2, "all-gather": 2}},
+    "x-int8": {"body": {"all-reduce": 6, "all-gather": 4,
+                        "all-to-all": 4},
+               "outer": {"all-reduce": 2, "all-gather": 2,
+                         "all-to-all": 2}},
 }
 
 
@@ -256,7 +266,7 @@ def check_attention_one_collective(mesh=None) -> list[str]:
     from repro.launch import hlo as hlo_lib
     from repro.models import attention as attn
     from repro.models.model import build_model
-    from repro.sharding import specs
+    from repro.sharding import act, specs
 
     mesh = mesh or _mesh()
     out = []
@@ -293,8 +303,9 @@ def check_attention_one_collective(mesh=None) -> list[str]:
         jitted = jax.jit(layer, in_shardings=(
             attn_sh, rep, pool_sh, rep, rep, rep))
         try:
-            text = jitted.lower(attn_sds, h, pool_sds, tables, pos,
-                                used).compile().as_text()
+            with act.use_mesh(mesh):
+                text = jitted.lower(attn_sds, h, pool_sds, tables, pos,
+                                    used).compile().as_text()
         except Exception as e:          # pragma: no cover - diagnostics
             out.append(f"attention[{label}]: meshed compile failed: "
                        f"{type(e).__name__}: {e}")
@@ -322,7 +333,7 @@ def check_tick_signature(mesh=None) -> list[str]:
 
     from repro.launch import hlo as hlo_lib
     from repro.models.model import build_model
-    from repro.sharding import specs
+    from repro.sharding import act, specs
 
     mesh = mesh or _mesh()
     out = []
@@ -344,7 +355,8 @@ def check_tick_signature(mesh=None) -> list[str]:
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
             (params, cache, tables, tokens, pos, used))
         try:
-            compiled = jitted.lower(*sds).compile()
+            with act.use_mesh(mesh):
+                compiled = jitted.lower(*sds).compile()
         except Exception as e:          # pragma: no cover - diagnostics
             out.append(f"tick[{label}]: meshed compile failed: "
                        f"{type(e).__name__}: {e}")

@@ -3,10 +3,14 @@
 TPU adaptation of the paper's weight-stationary CIM dataflow:
 the per-head ``W_QK`` tile is **resident in VMEM** (playing the SRAM
 array's role), and the *raw inputs* X stream through it — the dynamic
-matrices Q/K never exist. Both contractions run on the MXU's native
-int8×int8→int32 path (the idiomatic port of the multiplier-free
-bit-serial MAC; the bit-exact per-bit schedule lives in
-kernels/bitplane_mac).
+matrices Q/K never exist. The weight-stationary pass G = X·W_QK runs on
+the MXU's native int8×int8→int32 path (the idiomatic port of the
+multiplier-free bit-serial MAC; the bit-exact per-bit schedule lives in
+kernels/bitplane_mac). The second pass S = G·Yᵀ multiplies an int32 G,
+which the MXU does not take (Mosaic refuses an int32×int32 matmul), so
+it contracts in float32 at full precision — exactly the arithmetic of
+the jnp twin (ops.scores_jnp), and wide enough that S cannot overflow
+as an int32 accumulator would at macro-scale D·M.
 
 Grid (H, I, J): heads outer so each head's W_QK is loaded once and
 reused for all (I×J) score tiles — weight-stationary across the whole
@@ -61,7 +65,7 @@ def _score_kernel(x_ref, y_ref, w_ref, o_ref):
     """One (BN × BM) int32 score tile for one head.
 
     x_ref (BN, D) int8; y_ref (BM, D) int8; w_ref (1, D, D) int8;
-    o_ref (1, BN, BM) int32.
+    o_ref (1, BN, BM) float32.
     """
     x = x_ref[...]
     y = y_ref[...]
@@ -71,8 +75,9 @@ def _score_kernel(x_ref, y_ref, w_ref, o_ref):
         x, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32)
     # S = G · Y^T : second pass over the same stationary tile's output
     s = jax.lax.dot_general(
-        g, y.astype(jnp.int32), (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.int32)
+        g.astype(jnp.float32), y.astype(jnp.float32),
+        (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST)
     o_ref[0] = s
 
 
@@ -83,7 +88,7 @@ def wqk_score_int8(x_q: jax.Array, x_kv: jax.Array, wqk: jax.Array,
                    block_m: int = DEFAULT_BLOCK_M,
                    interpret: bool = False) -> jax.Array:
     """x_q (N, D) int8, x_kv (M, D) int8, wqk (H, D, D) int8
-    -> (H, N, M) int32 integer scores.
+    -> (H, N, M) float32 scores (integer G, float32 second pass).
 
     N and M must be multiples of the block sizes (ops.py pads).
     """
@@ -102,6 +107,6 @@ def wqk_score_int8(x_q: jax.Array, x_kv: jax.Array, wqk: jax.Array,
             pl.BlockSpec((1, D, D), w_index_map),
         ],
         out_specs=pl.BlockSpec((1, block_n, block_m), out_index_map),
-        out_shape=jax.ShapeDtypeStruct((H, N, M), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct((H, N, M), jnp.float32),
         interpret=interpret,
     )(x_q, x_kv, wqk)

@@ -69,13 +69,15 @@ def scores(x_q: jax.Array, x_kv: jax.Array, wqk: jax.Array, *,
 def scores_jnp(x_q: jax.Array, x_kv: jax.Array, wqk: jax.Array) -> jax.Array:
     """jnp twin of ``scores`` — same quantization scheme, no Pallas.
     Used for decode-shaped (Nq=1) calls where padding to a kernel block
-    would dominate. Second contraction accumulates in f32 (int32 would
-    overflow at macro-scale D·M)."""
+    would dominate. Second contraction runs in f32 at full precision,
+    the kernel's own arithmetic (int32 would overflow at macro-scale
+    D·M, and the MXU takes no int32 operands)."""
     qx, sx, qy, sy, qw, sw = _quantize_workload(x_q, x_kv, wqk)
     g = jnp.einsum("...nd,hde->...hne", qx.astype(jnp.int32),
                    qw.astype(jnp.int32))
     s = jnp.einsum("...hne,...me->...hnm", g.astype(jnp.float32),
-                   qy.astype(jnp.float32))
+                   qy.astype(jnp.float32),
+                   precision=jax.lax.Precision.HIGHEST)
     return _dequant(s, sx, sy, sw)
 
 

@@ -8,16 +8,9 @@ import jax.numpy as jnp
 def wqk_score_int8_ref(x_q: jax.Array, x_kv: jax.Array,
                        wqk: jax.Array) -> jax.Array:
     """x_q (N, D) int8, x_kv (M, D) int8, wqk (H, D, D) int8
-    -> (H, N, M) int32. Exact integer arithmetic."""
+    -> (H, N, M) int32. Exact integer arithmetic: the value the kernel's
+    float32 second pass rounds (tests bound the difference)."""
     g = jnp.einsum("nd,hde->hne", x_q.astype(jnp.int32),
                    wqk.astype(jnp.int32))
     return jnp.einsum("hne,me->hnm", g, x_kv.astype(jnp.int32))
 
-
-def wqk_score_f32_ref(x_q: jax.Array, x_kv: jax.Array, wqk: jax.Array,
-                      sx: jax.Array, sy: jax.Array,
-                      sw: jax.Array) -> jax.Array:
-    """Dequantized float scores given per-token scales sx (N,1), sy (M,1)
-    and per-tensor (or per-head (H,1,1)) sw."""
-    s = wqk_score_int8_ref(x_q, x_kv, wqk).astype(jnp.float32)
-    return s * sx[None, :, :] * jnp.swapaxes(sy, 0, 1)[None, :, :] * sw

@@ -10,13 +10,8 @@ import jax
 
 
 def make_mesh(shape, axes):
-    # jax.sharding.AxisType landed after 0.4.x; older jax only takes
-    # (shape, axis_names) and defaults every axis to Auto anyway
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+    return jax.make_mesh(shape, axes, axis_types=(
+        jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

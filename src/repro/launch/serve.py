@@ -27,6 +27,7 @@ import time
 import jax
 import numpy as np
 
+from repro import util
 from repro.configs.base import get_arch, reduced as reduce_cfg
 from repro.core import score_backend
 from repro.models import frontends
@@ -70,6 +71,7 @@ async def _stream_serve(eng, arrivals, args):
 
 
 def main():
+    util.enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")
@@ -223,11 +225,15 @@ def main():
                      **engine_kw)
     if not args.router and eng.plan is not None:
         budget = kvcache.budget_for(cfg)
+        dev = jax.devices()[0]
+        limit = (dev.memory_stats() or {}).get("bytes_limit")
+        fits = (f"{budget.max_tokens(limit):,} tokens in the {limit:,} B "
+                f"of one {dev.device_kind}" if limit else
+                f"{dev.device_kind} reports no memory limit")
         print(f"[serve] score backend {eng.plan.backend.name!r} "
               f"({'blockwise' if eng.plan.blockwise else 'quadratic'}); "
               f"cache mode {budget.mode!r}; "
-              f"{budget.bytes_per_token} B/token; "
-              f"{budget.max_tokens(16 << 30):,} tokens per 16 GB chip")
+              f"{budget.bytes_per_token} B/token; {fits}")
         print(f"[serve] plan: {eng.plan.reason}")
     if not args.router and eng.paged:
         pb = kvcache.paged_budget_for(cfg, args.block_size)

@@ -16,6 +16,7 @@ import dataclasses
 
 import jax
 
+from repro import util
 from repro.configs.base import get_arch, reduced as reduce_cfg
 from repro.data.pipeline import DataConfig, make_batch
 from repro.launch.mesh import make_host_mesh, make_production_mesh
@@ -27,6 +28,7 @@ from repro.train.trainer import TrainConfig, Trainer
 
 
 def main():
+    util.enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--steps", type=int, default=1000)

@@ -405,14 +405,16 @@ def _decode_attend(p: dict, x_new: jax.Array, q, view: KVCache,
     if window is not None:
         ok = ok & (idx > qpos[:, :, None] - window)
     s = s + jnp.where(ok, 0.0, NEG_INF)[:, None, :, :]
-    a = jax.nn.softmax(s, axis=-1).astype(dt)
+    # probabilities and the AV contraction stay float32, as in the
+    # streamed kernel: one rounding to the model dtype, at the output
+    a = jax.nn.softmax(s, axis=-1)
 
     ag = a.reshape(B, Hkv, H // Hkv, n, S)
     o = jnp.einsum("bgrns,bsge->bgrne", ag,
-                   v_src.astype(dt)).reshape(B, H, n, dh)
+                   v_src.astype(jnp.float32)).reshape(B, H, n, dh)
     from repro.sharding import act
     o = act.constrain_heads(o)      # TP: one combine, at the wo einsum
-    return jnp.einsum("bhne,hed->bnd", o, p["wo"].astype(dt))
+    return jnp.einsum("bhne,hed->bnd", o.astype(dt), p["wo"].astype(dt))
 
 
 def _decode_attend_streamed(p: dict, x_new: jax.Array, q, pool: KVCache,
@@ -433,10 +435,8 @@ def _decode_attend_streamed(p: dict, x_new: jax.Array, q, pool: KVCache,
     softcap = float(cfg.logit_softcap or 0.0)
 
     if not be.uses_x_cache:
-        o = paged_attend(q.astype(jnp.float32), pool.k, tables,
-                         blocks_used, qpos, v_pool=pool.v,
-                         k_scale=pool.ks, v_scale=pool.vs, scale=scale,
-                         window=window, softcap=softcap)
+        o = _stream_kv(q.astype(jnp.float32), pool, tables, blocks_used,
+                       qpos, window, scale=scale, softcap=softcap)
     else:
         qe = be.stream_q(score_weights(p), x_new)       # (B, H, n, Daug)
         aug = qe.shape[-1] == pool.x.shape[-1] + 1
@@ -455,6 +455,39 @@ def _decode_attend_streamed(p: dict, x_new: jax.Array, q, pool: KVCache,
     from repro.sharding import act
     o = act.constrain_heads(o)      # TP: one combine, at the wo einsum
     return jnp.einsum("bhne,hed->bnd", o.astype(dt), p["wo"].astype(dt))
+
+
+def _stream_kv(q, pool: KVCache, tables, blocks_used, qpos, window,
+               **kw) -> jax.Array:
+    """kv-layout ``paged_attend``. Under an installed mesh it runs per
+    device through ``jax.shard_map``: heads on "model", tables,
+    ``blocks_used``, ``qpos`` and the window replicated. GSPMD cannot
+    partition a Pallas kernel, and the heads need no communication —
+    each device streams its own head-slice of the pool. When Hkv does
+    not divide the model axis (the ``NonDividingShardWarning`` layout)
+    every operand replicates and each device computes all heads."""
+    from repro.kernels.paged_attention import paged_attend
+    from repro.sharding import act
+
+    def local(q, k, v, ks, vs, tb, bu, qp, win):
+        return paged_attend(q, k, tb, bu, qp, v_pool=v, k_scale=ks,
+                            v_scale=vs, window=win, **kw)
+
+    args = (q, pool.k, pool.v, pool.ks, pool.vs, tables, blocks_used,
+            qpos, window)
+    mesh = act.current_mesh()
+    if mesh is None:
+        return local(*args)
+    from jax.sharding import PartitionSpec as P
+    head = "model" if pool.k.shape[2] % mesh.shape["model"] == 0 else None
+    heads, rows = P(None, head), P(None, None, head)
+    # check_vma off: the jnp twin's while-loop carries start from
+    # constants, which the varying-axes check rejects against its
+    # head-varying updates
+    return jax.shard_map(
+        local, mesh=mesh,
+        in_specs=(heads, rows, rows, rows, rows, P(), P(), P(), P()),
+        out_specs=heads, check_vma=False)(*args)
 
 
 def attention_decode_paged(p: dict, x_new: jax.Array, pool: KVCache,

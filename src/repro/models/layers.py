@@ -125,9 +125,13 @@ def embed(tokens: jax.Array, table: jax.Array) -> jax.Array:
 
 def unembed(x: jax.Array, table_or_head: jax.Array,
             tied: bool) -> jax.Array:
+    # float32 logits: a bf16 vocabulary row ties its top two entries
+    # often enough to make greedy decoding hinge on rounding
     if tied:
-        return jnp.einsum("...d,vd->...v", x, table_or_head)
-    return jnp.einsum("...d,dv->...v", x, table_or_head)
+        return jnp.einsum("...d,vd->...v", x, table_or_head,
+                          preferred_element_type=jnp.float32)
+    return jnp.einsum("...d,dv->...v", x, table_or_head,
+                      preferred_element_type=jnp.float32)
 
 
 # ------------------------------------------------------------ chunked x-ent

@@ -311,14 +311,18 @@ class Model:
                 f"paged cache unsupported for family {self.cfg.family!r}")
         cfg = self.cfg
         dt = _dtype(cfg)
-        pool = {"attn": _stack_pytrees(
-            [attn.init_kv_cache(cfg, num_blocks, block_size, dt)
-             for _ in range(cfg.num_layers)])}
-        if mesh is not None:
-            from repro.sharding import specs
-            pool = jax.device_put(pool,
-                                  specs.paged_pool_shardings(pool, mesh))
-        return pool
+
+        def build():
+            return {"attn": _stack_pytrees(
+                [attn.init_kv_cache(cfg, num_blocks, block_size, dt)
+                 for _ in range(cfg.num_layers)])}
+        if mesh is None:
+            return jax.jit(build)()
+        # allocate each shard on its own device: a whole pool built on
+        # one device first would not fit there beside the weights
+        from repro.sharding import specs
+        return jax.jit(build, out_shardings=specs.paged_pool_shardings(
+            jax.eval_shape(build), mesh))()
 
     def decode_paged(self, p, cache, tables, tokens, pos,
                      blocks_used=None):

@@ -78,9 +78,13 @@ resident per macro; only raw inputs stream):
     the allocator, copy-on-write prefix sharing and eviction run
     unchanged host-side;
   * prefill chunks and decode ticks run the same jitted graphs under
-    ``NamedSharding``; per-head attention partials are pinned to their
-    shard (``sharding/act.constrain_heads``) so the only TP collective
-    per tick is the one combine at the wo projection.
+    ``NamedSharding``; the paged kernel runs per device under
+    ``shard_map`` on its head-slice (``models/attention._stream_kv``)
+    and per-head partials stay on their shard, so the only TP
+    collective per attention layer is the one combine at the wo
+    projection. On a TPU mesh, X-stream plans are refused
+    (``check_mesh_plan``): their pool rows split D, which the kernel
+    contracts over.
 
 Backends whose score path cannot split by head (``plan.shards_heads``
 False, e.g. ``factored``'s shared K projection) fall back to a
@@ -143,6 +147,23 @@ class NonDividingShardWarning(UserWarning):
 # every engine built on the same fallback layout after the first stays
 # quiet, so sweeps/tests don't drown in repeats
 _NONDIV_WARNED: set = set()
+
+
+def check_mesh_plan(plan, decode_schedule: str, platform: str) -> None:
+    """Refuse a plan that a mesh with a sharded pool cannot compile. An
+    X-stream layout shards its pool rows over D
+    (``sharding/specs.paged_pool_spec``), and on a TPU the streamed
+    schedule runs the Pallas paged kernel, which contracts over D: a
+    kernel cannot run on a split D, and GSPMD cannot partition it. The
+    CPU runs the jnp stream, which GSPMD partitions."""
+    if (platform == "tpu" and decode_schedule == "stream"
+            and plan.backend.uses_x_cache):
+        raise ValueError(
+            f"score backend {plan.backend.name!r} streams the "
+            f"{plan.cache_mode!r} X-cache, whose pool rows split D over "
+            f"the mesh's model axis; the Pallas paged kernel contracts "
+            f"over D and cannot run on a split D. Serve this plan on "
+            f"one chip, or with decode_schedule='gather'.")
 
 
 @dataclasses.dataclass
@@ -369,6 +390,9 @@ class Engine:
             planned = self.plan.decode_schedule if self.plan else "gather"
             self.decode_schedule = planned if decode_schedule == "auto" \
                 else decode_schedule
+            if self._shard_pool:
+                check_mesh_plan(self.plan, self.decode_schedule,
+                                mesh.devices.flat[0].platform)
             self.pool = model.init_paged_cache(
                 num_blocks, block_size,
                 mesh=mesh if self._shard_pool else None)

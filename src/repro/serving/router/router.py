@@ -173,11 +173,19 @@ class ReplicaRouter:
         model axis). ``disaggregate=True`` splits every replica into a
         ``prefill_slots``-slot prefill worker and a decode worker
         (``engine_kw`` sizes the decode side)."""
+        import jax
+
         from repro.launch.mesh import replica_submeshes
         from repro.serving.router.disagg import DisaggReplica
+        from repro.sharding import specs
 
         replicas: list = []
         for sub in replica_submeshes(mesh):
+            # one copy of the weights per replica: its prefill and decode
+            # workers read the same arrays (Engine's own device_put of an
+            # array already laid out on ``sub`` is a no-op)
+            params_sub = jax.device_put(
+                params, specs.param_shardings(params, sub))
             if disaggregate:
                 pre_kw = dict(engine_kw)
                 # the prefill worker only ever holds prompt blocks, and
@@ -186,12 +194,12 @@ class ReplicaRouter:
                 pre_kw.update(max_slots=prefill_slots, prefill_only=True)
                 dec_kw = dict(engine_kw)
                 dec_kw.pop("radix_cache", None)
-                pre = Engine(model, params, mesh=sub, **pre_kw)
-                dec = Engine(model, params, mesh=sub, **dec_kw)
+                pre = Engine(model, params_sub, mesh=sub, **pre_kw)
+                dec = Engine(model, params_sub, mesh=sub, **dec_kw)
                 replicas.append(DisaggReplica(pre, dec))
             else:
                 replicas.append(FusedReplica(
-                    Engine(model, params, mesh=sub, **engine_kw)))
+                    Engine(model, params_sub, mesh=sub, **engine_kw)))
         return cls(replicas, policy=policy, admit_scan=admit_scan)
 
     # ----------------------------------------------------------- hooks

@@ -22,25 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-# jax.shard_map was promoted out of jax.experimental after 0.4.x, and
-# the varying-manual-axes (vma) marking via jax.lax.pcast arrived with
-# it. On older jax: use the experimental entry point with the
-# replication checker off (it predates vma and rejects ppermute
-# carries), and pcast degrades to identity.
-_shard_map = getattr(jax, "shard_map", None)
-if _shard_map is None:
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
-
-    def _shard_map(f, *, mesh, in_specs, out_specs):
-        return _exp_shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=False)
-
-
-def _mark_varying(x, axis):
-    pcast = getattr(jax.lax, "pcast", None)
-    return x if pcast is None else pcast(x, (axis,), to="varying")
-
-
 NEG_INF = -1e30
 
 
@@ -68,7 +49,7 @@ def ring_attention(q, k, v, pos_q, pos_k, mesh: Mesh, axis: str, *,
         H, n_l, E = q_l.shape
         dv = v_l.shape[-1]
         # carries must be marked varying over the ring axis (vma check)
-        mark = lambda x: _mark_varying(x, axis)
+        mark = lambda x: jax.lax.pcast(x, (axis,), to="varying")
         acc = mark(jnp.zeros((H, n_l, dv), jnp.float32))
         m = mark(jnp.full((H, n_l, 1), NEG_INF, jnp.float32))
         l = mark(jnp.zeros((H, n_l, 1), jnp.float32))
@@ -95,7 +76,7 @@ def ring_attention(q, k, v, pos_q, pos_k, mesh: Mesh, axis: str, *,
             0, p_sz, step, (acc, m, l, k_l, v_l, pk_l))
         return acc / jnp.maximum(l, 1e-30)
 
-    shard = _shard_map(
+    shard = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(None, axis, None), P(None, axis, None),
                   P(None, axis, None), P(axis), P(axis)),
@@ -122,7 +103,7 @@ def ring_attention_wqk(g, x_kv, wv, pos_q, pos_k, mesh: Mesh, axis: str, *,
     def local(g_l, x_l, pq_l, pk_l):
         n_l = g_l.shape[1]
         dh = wv.shape[-1]
-        mark = lambda x: _mark_varying(x, axis)
+        mark = lambda x: jax.lax.pcast(x, (axis,), to="varying")
         acc = mark(jnp.zeros((H, n_l, dh), jnp.float32))
         m = mark(jnp.full((H, n_l, 1), NEG_INF, jnp.float32))
         l = mark(jnp.zeros((H, n_l, 1), jnp.float32))
@@ -149,7 +130,7 @@ def ring_attention_wqk(g, x_kv, wv, pos_q, pos_k, mesh: Mesh, axis: str, *,
             0, p_sz, step, (acc, m, l, x_l, pk_l))
         return acc / jnp.maximum(l, 1e-30)
 
-    shard = _shard_map(
+    shard = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(None, axis, None), P(axis, None), P(axis), P(axis)),
         out_specs=P(None, axis, None))

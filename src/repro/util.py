@@ -4,6 +4,26 @@ from __future__ import annotations
 import os
 
 _UNROLL_ENV = "REPRO_UNROLL_SCANS"
+# <checkout>/.jax_cache (gitignored): fixed, because the cache directory
+# is part of what a later run must find again
+_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; entry points call this
+    first thing in ``main`` (never at import). Where
+    ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and this
+    sets nothing; otherwise the cache goes to ``<checkout>/.jax_cache``.
+    Returns the directory in use. libtpu's own flags are left alone:
+    nothing here reads or writes ``LIBTPU_INIT_ARGS``."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+    jax.config.update("jax_compilation_cache_dir", _CACHE_DIR)
+    return _CACHE_DIR
 
 
 def scan_unroll():

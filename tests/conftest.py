@@ -36,8 +36,12 @@ def pytest_collection_modifyitems(config, items):
 def forced_devices_env(num_devices=None):
     """Subprocess env for tests that force a host device count. The
     override must be set BEFORE the child's jax import and must never
-    touch this (possibly xdist-worker) process's environment."""
-    env = {"PYTHONPATH": "src", "PATH": "/usr/bin:/bin"}
+    touch this (possibly xdist-worker) process's environment. The child
+    is pinned to the CPU platform: forced host devices are CPU devices,
+    and a child that probed for an accelerator would wait on (or take)
+    a chip another process holds."""
+    env = {"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
+           "JAX_PLATFORMS": "cpu"}
     if num_devices:
         env["XLA_FLAGS"] = ("--xla_force_host_platform_device_count="
                             f"{num_devices}")

@@ -19,8 +19,18 @@ def test_wqk_score_kernel_exact(rng, shape):
     xk = jnp.asarray(rng.integers(-127, 128, (M, D)), jnp.int8)
     w = jnp.asarray(rng.integers(-127, 128, (H, D, D)), jnp.int8)
     out = wqk_score_int8(xq, xk, w, block_n=64, block_m=64, interpret=True)
-    np.testing.assert_array_equal(
-        np.asarray(out), np.asarray(ref.wqk_score_int8_ref(xq, xk, w)))
+    exact = np.asarray(ref.wqk_score_int8_ref(xq, xk, w), np.float64)
+    # G = X·W_QK is exact int32 (|G| <= D·127² < 2^24, so its float32
+    # cast is exact too); S = G·Yᵀ contracts in float32, whose rounding
+    # is bounded by gamma_D · Σ|G||Y|, gamma_D = D·u / (1 - D·u)
+    g = np.einsum("nd,hde->hne", np.asarray(xq, np.int64),
+                  np.asarray(w, np.int64))
+    mag = np.einsum("hne,me->hnm", np.abs(g),
+                    np.abs(np.asarray(xk, np.int64))).astype(np.float64)
+    u = 2.0 ** -24
+    bound = D * u / (1 - D * u) * mag
+    assert out.dtype == jnp.float32
+    assert np.all(np.abs(np.asarray(out, np.float64) - exact) <= bound)
 
 
 def test_wqk_score_ops_padding_and_batch(rng):

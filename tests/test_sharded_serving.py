@@ -102,6 +102,20 @@ def test_shards_heads_capability_in_plan():
     assert not sb.plan(_cfg(score_mode="factored")).shards_heads
 
 
+def test_tpu_mesh_refuses_x_stream_plans():
+    """A sharded X pool splits D over the model axis; the Pallas paged
+    kernel contracts over D, so a TPU mesh refuses the streamed X plan
+    instead of compiling something else. kv plans, the gather schedule
+    and CPU meshes (jnp stream under GSPMD) pass."""
+    from repro.serving.engine import check_mesh_plan
+    x = sb.plan(_cfg(score_mode="wqk_int8", cache_quant="int8"))
+    with pytest.raises(ValueError, match="split D"):
+        check_mesh_plan(x, "stream", "tpu")
+    check_mesh_plan(x, "gather", "tpu")
+    check_mesh_plan(x, "stream", "cpu")
+    check_mesh_plan(sb.plan(_cfg(score_mode="standard")), "stream", "tpu")
+
+
 # ---------------------------------------------- admission at equal HBM
 
 def test_admission_scales_with_per_device_budget():
