@@ -1,0 +1,145 @@
+"""The benchmark's one point of contact with the serving program.
+
+It builds what ``launch/serve.py`` builds (``Engine`` over the paged
+pool with the default plan, the async front end over it) from a
+configuration file, hands it the benchmark's weights, and wraps two
+methods of the engine instance, ``tick`` and ``admit``, to record when
+each ran, to name it in a profiler trace, and to stop the engine when
+the window closes. Nothing else of the program is read.
+"""
+from __future__ import annotations
+
+import dataclasses
+import os
+import sys
+import threading
+import time
+
+import jax
+
+clock = time.monotonic
+
+
+class WindowClosed(Exception):
+    """Raised in the engine's pump thread to stop it at the window's
+    end, without draining the requests still in flight."""
+
+
+def import_program(root: str) -> None:
+    src = os.path.join(root, "src")
+    if not os.path.isdir(os.path.join(src, "repro")):
+        raise FileNotFoundError(f"no serving program under {src}")
+    if src not in sys.path:
+        sys.path.insert(0, src)
+
+
+def model_config(config: dict):
+    from repro.configs.base import ModelConfig
+    d, h = config["hidden_size"], config["num_attention_heads"]
+    if config["hidden_act"] != "silu":
+        raise ValueError(f"hidden_act {config['hidden_act']!r}")
+    return ModelConfig(
+        name=config["name"], family="dense",
+        num_layers=config["num_hidden_layers"], d_model=d, num_heads=h,
+        num_kv_heads=config["num_key_value_heads"],
+        head_dim=config.get("head_dim", d // h),
+        d_ff=config["intermediate_size"], vocab_size=config["vocab_size"],
+        qkv_bias=bool(config["qkv_bias"]),
+        rope_theta=float(config["rope_theta"]),
+        tie_embeddings=bool(config["tie_word_embeddings"]),
+        dtype=config["torch_dtype"])
+
+
+def engine_options(config: dict, traffic: dict) -> dict:
+    return {**config["engine"], **traffic.get("engine", {})}
+
+
+def build_engine(config: dict, traffic: dict, params):
+    from repro.models.model import build_model
+    from repro.serving.engine import Engine
+    opts = engine_options(config, traffic)
+    return Engine(build_model(model_config(config)), params,
+                  max_slots=opts["max_slots"], max_len=opts["max_len"],
+                  block_size=opts["block_size"],
+                  hbm_bytes=opts["hbm_bytes"],
+                  radix_cache=bool(opts.get("radix_cache", False)))
+
+
+def request(rid: int, tokens: list, max_new: int):
+    from repro.serving.engine import Request
+    return Request(rid=rid, tokens=tokens, max_new_tokens=max_new,
+                   eos_id=None)
+
+
+def warm_up(engine) -> None:
+    """Compile every shape the window uses: the prefill chunk (first
+    and later chunks), the decode tick and the sampling of both."""
+    c = engine.prefill_chunk
+    engine.run([request(-1, [3] * (c + 6), 3), request(-2, [4] * 5, 3)])
+
+
+@dataclasses.dataclass
+class Tick:
+    start: float
+    end: float
+    contexts: list          # keys each active sequence attends
+
+
+@dataclasses.dataclass
+class Admission:
+    rid: int
+    start: float
+    end: float
+    chunks: list            # (first position, tokens) of each chunk run
+
+
+class Probe:
+    """Wraps ``tick`` and ``admit`` of one engine instance."""
+
+    def __init__(self, engine):
+        self.engine = engine
+        self.stop = threading.Event()
+        self.annotate = False          # name the calls in a trace
+        self.ticks: list[Tick] = []
+        self.admissions: list[Admission] = []
+        self._tick, self._admit = engine.tick, engine.admit
+        engine.tick, engine.admit = self.tick, self.admit
+
+    def tick(self):
+        if self.stop.is_set():
+            raise WindowClosed
+        eng = self.engine
+        ctx = [int(p) + 1 for p, r in zip(eng.pos, eng.slot_req,
+                                          strict=True) if r is not None]
+        t = clock()
+        if self.annotate:
+            with jax.profiler.TraceAnnotation(
+                    "bench.tick", index=len(self.ticks)):
+                self._tick()
+        else:
+            self._tick()
+        self.ticks.append(Tick(t, clock(), ctx))
+
+    def admit(self, req) -> bool:
+        if self.stop.is_set():
+            raise WindowClosed
+        t = clock()
+        if self.annotate:
+            with jax.profiler.TraceAnnotation(
+                    "bench.admit", index=len(self.admissions)):
+                ok = self._admit(req)
+        else:
+            ok = self._admit(req)
+        if ok:
+            self.admissions.append(Admission(
+                req.rid, t, clock(), self._chunks(req)))
+        return ok
+
+    def _chunks(self, req) -> list:
+        eng = self.engine
+        shared = 0
+        if req in eng.slot_req:
+            shared = eng.seq_blocks[eng.slot_req.index(req)].num_shared
+        c, n = eng.prefill_chunk, len(req.tokens)
+        return [(c0, min(c, n - c0))
+                for c0 in range(shared * eng.block_size, n, c)]

@@ -1,0 +1,101 @@
+"""The cost functions against counts worked out by hand."""
+from __future__ import annotations
+
+import json
+import os
+
+import pytest
+
+from bench import costs
+from bench.spec import BENCH_DIR
+
+
+def _shapes(name):
+    with open(os.path.join(BENCH_DIR, "configs", name + ".json")) as f:
+        return costs.Shapes.of(json.load(f))
+
+
+# per layer, one token: q, k, v, o projections and the three
+# feed-forward matrices
+QWEN_LAYER = 5120 * 5120 + 2 * 5120 * 1024 + 5120 * 5120 + 3 * 5120 * 13824
+INTERN_LAYER = 6144 * 6144 + 2 * 6144 * 1024 + 6144 * 6144 \
+    + 3 * 6144 * 16384
+CASES = {
+    # name: (layers, matmul params per layer, hidden, vocab, heads,
+    #        bias entries per layer, bytes of one token's k and v)
+    "qwen2.5-14b-8L": (8, QWEN_LAYER, 5120, 152064, 40, 7168, 4096),
+    "internlm2-20b-6L": (6, INTERN_LAYER, 6144, 92544, 48, 0, 4096),
+}
+
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_layer_params(name):
+    s = _shapes(name)
+    L, P, d, V, H, bias, kv = CASES[name]
+    assert s.layer_matmul_params == P
+    assert s.layer_params == P + bias + 2 * d
+    assert s.kv_row_bytes == kv
+    assert s.weight_bytes() == 2 * (L * (P + bias + 2 * d) + d + d * V)
+
+
+def test_qwen_sizes_match_the_configuration_file():
+    s = _shapes("qwen2.5-14b-8L")
+    assert QWEN_LAYER == 275_251_200
+    total = s.layers * s.layer_params + 2 * s.d * s.vocab + s.d
+    assert round(total / 1e9, 2) == 3.76        # "3.76 B parameters"
+
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_decode_tick(name):
+    s = _shapes(name)
+    L, P, d, V, H, bias, kv = CASES[name]
+    ctx = [100, 200, 1]
+    f, b = costs.decode_tick(s, ctx)
+    att = 4 * H * 128 * (100 + 200 + 1)          # QK and AV, per layer
+    assert f == 3 * 2 * (L * P + d * V) + L * att
+    weights = 2 * (L * (P + bias + 2 * d) + d + d * V)
+    assert b == weights + 3 * (2 * d + 4 * V) + L * kv * 301
+
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_prefill_chunk(name):
+    s = _shapes(name)
+    L, P, d, V, H, bias, kv = CASES[name]
+    f, b = costs.prefill_chunk(s, 0, 64, last=False)
+    keys = 64 * 65 // 2                            # causal: 1 + ... + 64
+    assert f == 64 * 2 * L * P + L * 4 * H * 128 * keys
+    assert b == 2 * L * (P + bias + 2 * d) + 64 * d * 2 + L * kv * 64
+    # a later, final chunk: 10 tokens at 64..73, head for the last one
+    f2, b2 = costs.prefill_chunk(s, 64, 10, last=True)
+    keys2 = sum(range(65, 75))
+    assert f2 == 10 * 2 * L * P + L * 4 * H * 128 * keys2 + 2 * d * V
+    assert b2 == 2 * L * (P + bias + 2 * d) + 10 * d * 2 + L * kv * 74 \
+        + 2 * (d + d * V) + 4 * V
+
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_paged_kernel_bytes(name):
+    s = _shapes(name)
+    H = CASES[name][4]
+    # n = 1: two sequences of 100 and 200 keys, 16-token blocks
+    f, b = costs.paged_kernel(s, [100, 200], 1, 16)
+    assert b == (7 + 13) * 16 * 4096 + 2 * 2 * 1 * H * 128 * 4
+    assert f == 4 * H * 128 * 300
+    # n = 64: one chunk whose last query attends 1000 keys
+    f, b = costs.paged_kernel(s, [1000], 64, 16)
+    assert b == 63 * 16 * 4096 + 2 * 64 * H * 128 * 4
+    assert f == 4 * H * 128 * (64 * 1000 - 63 * 64 // 2)
+
+
+def test_roofline_bound_and_peaks():
+    p = costs.load_peaks("TPU v5 lite")
+    assert p["bf16_flop_s"] == 197e12 and p["hbm_byte_s"] == 819e9
+    t, which = costs.min_seconds(197e12, 1.0, p)
+    assert (t, which) == (1.0, "compute")
+    t, which = costs.min_seconds(1.0, 819e9, p)
+    assert (t, which) == (1.0, "memory")
+
+
+def test_unknown_device_is_an_error():
+    with pytest.raises(KeyError, match="no peaks"):
+        costs.load_peaks("TPU v9 imaginary")
