@@ -199,7 +199,7 @@ def serve_phase(cfg) -> bool:
     print(f"[serve] compile + warm-up (prefill chunk and decode tick "
           f"graphs): {time.perf_counter() - t:.1f} s", flush=True)
     with eng._mesh_ctx():
-        tick = eng._decode_paged.lower(
+        tick = eng._decode_tick.lower(
             eng.params, eng.pool, eng._dev(eng.tables),
             eng._dev(np.zeros((eng.max_slots, 1), np.int32)),
             eng._dev(eng.pos), eng._blocks_used(eng.pos)).as_text()

@@ -234,5 +234,6 @@ def paged_attend_pallas(q: jax.Array, k_pool: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, n, dv), jnp.float32),
         interpret=interpret,
+        name="paged_attention",
     )(tables.astype(jnp.int32), used, qpos.astype(jnp.int32), win,
       *operands)

@@ -55,6 +55,13 @@ row depends only on its token prefix. ``on_token``/``on_finish`` hooks
 fire host-side per appended token / finished request (None by default:
 the sync path is unchanged).
 
+**Profiler names** (``serving/spans.py``): the tick and the chunk run
+as two jitted programs over the one ``decode_paged`` graph, named
+``decode_tick`` and ``prefill_chunk`` in a trace; each host phase of a
+tick and of an admission is a named span; and the engine keeps
+monotonic counters (``ticks``, ``slot_ticks``, ``peak_active``) that
+readers difference over a window.
+
 ``capture_trace=True`` attaches a ``repro.sim`` score-trace hook: every
 prefill chunk and decode tick records its quantized score-operand
 shapes (logical + schedule-padded) and exact bit-sparsity tallies into
@@ -105,6 +112,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.serving import paged as paged_lib
+from repro.serving import spans
 from repro.sharding import act
 
 
@@ -209,13 +217,15 @@ class PrefillJob:
             C = eng.prefill_chunk
             c0 = self.c0
             chunk = self.ctx[c0:c0 + C]
-            buf = np.zeros((1, C), np.int32)
-            buf[0, :len(chunk)] = chunk
-            with eng._mesh_ctx():
-                self.logits, eng.pool = eng._decode_paged(
-                    eng.params, eng.pool, self.trow, eng._dev(buf),
-                    eng._dev(np.asarray([c0], np.int32)),
-                    eng._blocks_used(np.asarray([c0 + C - 1])))
+            with spans.span(spans.ADMIT_CHUNK, rid=self.req.rid, c0=c0):
+                buf = np.zeros((1, C), np.int32)
+                buf[0, :len(chunk)] = chunk
+                with eng._mesh_ctx():
+                    self.logits, eng.pool = eng._decode_paged(
+                        eng.params, eng.pool, self.trow, eng._dev(buf),
+                        eng._dev(np.asarray([c0], np.int32)),
+                        eng._blocks_used(np.asarray([c0 + C - 1])),
+                        False)
             if eng.trace is not None:
                 # queries: this chunk; keys: every position the graph
                 # scores it against (the schedule covers the padded
@@ -238,8 +248,9 @@ class PrefillJob:
             tok = req.output[-1]
         else:
             assert self.logits is not None   # cap guarantees >= 1 chunk
-            tok = int(eng._sample(
-                self.logits[:, self.plen - 1 - self.last_c0], [req])[0])
+            with spans.span(spans.ADMIT_SAMPLE, rid=req.rid):
+                tok = int(eng._sample(
+                    self.logits[:, self.plen - 1 - self.last_c0], [req])[0])
             req.output.append(tok)
             if eng.on_token:
                 eng.on_token(req, tok)
@@ -349,7 +360,10 @@ class Engine:
         # sampling base key: per-slot draws fold in (rid, token index)
         # so a request's sampled tokens never depend on co-scheduling
         self._base_key = jax.random.PRNGKey(rng_seed)
+        # monotonic totals since construction: decode ticks, active
+        # slots summed over ticks, the most slots ever active at once
         self.ticks = 0
+        self.slot_ticks = 0
         self.peak_active = 0
         self.preemptions = 0
         # how deep Engine.run / the schedulers scan the pending queue
@@ -420,23 +434,28 @@ class Engine:
             self._tables_dev = None        # device copy, refreshed lazily
             self.seq_blocks: list[paged_lib.SeqBlocks | None] = \
                 [None] * max_slots
-            if mesh is None:
-                self._decode_paged = jax.jit(model.decode_paged)
-            else:
+            # one graph, two programs named for a trace (jit_decode_tick,
+            # jit_prefill_chunk), one shape each. Per-engine wrappers,
+            # NOT bound methods: jax's trace cache keys on function
+            # identity and bakes this mesh's sharding constraints into
+            # the jaxpr — two replicas jitting one function would share
+            # one trace and cross-wire their device groups. Engines
+            # without a mesh lose nothing by it: jax traces and compiles
+            # again for each engine even through one bound method.
+            def decode_tick(*a):
+                return model.decode_paged(*a)
+
+            def prefill_chunk(*a):
+                return model.decode_paged(*a)
+            jit_kw = {}
+            if mesh is not None:
                 # pin the outputs: logits replicate (host samples them),
                 # the pool keeps its shard layout across ticks
                 pool_sh = jax.tree_util.tree_map(lambda l: l.sharding,
                                                  self.pool)
-                # per-engine wrapper, NOT the bound method: jax's trace
-                # cache keys on function identity and bakes this mesh's
-                # sharding constraints into the jaxpr — two replicas
-                # jitting model.decode_paged directly would share one
-                # trace and cross-wire their device groups
-                def _decode_paged_fn(*a):
-                    return model.decode_paged(*a)
-                self._decode_paged = jax.jit(
-                    _decode_paged_fn,
-                    out_shardings=(self._rep, pool_sh))
+                jit_kw["out_shardings"] = (self._rep, pool_sh)
+            self._decode_tick = jax.jit(decode_tick, **jit_kw)
+            self._prefill_chunk = jax.jit(prefill_chunk, **jit_kw)
         else:
             self.decode_schedule = "gather"      # dense pool: no paging
             self.cache = model.init_cache(max_slots, max_len)
@@ -538,23 +557,24 @@ class Engine:
         bit-equal to the pre-preemption rows) and decoding continues
         from the last sampled token without drawing a fresh admission
         sample."""
-        self.check_servable(req)
-        resume = bool(req.output)
-        # cache context: every token whose row must exist before the
-        # next decode tick feeds req.output[-1] (fresh: the prompt)
-        ctx = req.tokens + req.output[:-1] if resume else req.tokens
-        if self.paged:
-            job = self.begin_prefill(req)
-            if job is None:
-                return False
-            while not job.step():       # fused: drive every chunk now
-                pass
-            slot = job.slot
-        else:
-            slot = self._admit_dense(req, ctx, resume)
-            if slot is None:
-                return False
-        return self._post_admit(req, slot, resume)
+        with spans.span(spans.ADMIT, rid=req.rid):
+            self.check_servable(req)
+            resume = bool(req.output)
+            # cache context: every token whose row must exist before the
+            # next decode tick feeds req.output[-1] (fresh: the prompt)
+            ctx = req.tokens + req.output[:-1] if resume else req.tokens
+            if self.paged:
+                job = self.begin_prefill(req)
+                if job is None:
+                    return False
+                while not job.step():       # fused: drive every chunk now
+                    pass
+                slot = job.slot
+            else:
+                slot = self._admit_dense(req, ctx, resume)
+                if slot is None:
+                    return False
+            return self._post_admit(req, slot, resume)
 
     def _post_admit(self, req: Request, slot: int, resume: bool) -> bool:
         """Admission epilogue once the slot is live: clear a resume's
@@ -681,6 +701,10 @@ class Engine:
         sequence) once the job reports done."""
         if not self.paged:
             raise ValueError("begin_prefill requires the paged cache")
+        with spans.span(spans.ADMIT_RESERVE, rid=req.rid):
+            return self._reserve(req)
+
+    def _reserve(self, req: Request) -> PrefillJob | None:
         self.check_servable(req)
         resume = bool(req.output)
         ctx = req.tokens + req.output[:-1] if resume else req.tokens
@@ -910,6 +934,14 @@ class Engine:
         return self._dev(np.clip(used, 1, self.blocks_per_seq)
                          .astype(np.int32))
 
+    def _decode_paged(self, params, pool, tables, tokens, pos, used,
+                      tick: bool):
+        """The paged step through ``decode_tick`` (``tick``) or
+        ``prefill_chunk``. Ticks and chunks both call this, so one
+        wrapper around it sees every step."""
+        step = self._decode_tick if tick else self._prefill_chunk
+        return step(params, pool, tables, tokens, pos, used)
+
     def _sample(self, logits, reqs) -> np.ndarray:
         """Next token per row: greedy where the row's temperature is 0,
         else categorical over ``logits / temp``. The categorical key is
@@ -949,8 +981,14 @@ class Engine:
                 f"tick with in-flight prefill jobs in slots "
                 f"{sorted(self._prefilling)}; drive them to completion "
                 f"(or cancel) first")
-        if all(r is None for r in self.slot_req):
+        active = np.asarray([r is not None for r in self.slot_req])
+        if not active.any():
             return
+        with spans.span(spans.TICK, index=self.ticks,
+                        active=int(active.sum())):
+            self._tick(active)
+
+    def _tick(self, active: np.ndarray):
         if self.trace is not None:
             for s, req in enumerate(self.slot_req):
                 if req is None:
@@ -959,46 +997,54 @@ class Engine:
                 self.trace.record(
                     "decode", toks_all[-1:], toks_all,
                     n_kv_sched=self._sched_rows(int(self.pos[s])))
-        toks = self._dev(self.last_tok)
-        pos = self._dev(self.pos)
-        if self.paged:
-            # tables only change at admit/evict — reuse the device copy
-            # across decode ticks instead of re-uploading every step
-            if self._tables_dev is None:
-                self._tables_dev = self._dev(self.tables)
+        with spans.span(spans.TICK_UPLOAD):
+            toks = self._dev(self.last_tok)
+            pos = self._dev(self.pos)
+            if self.paged:
+                # tables only change at admit/evict — reuse the device
+                # copy across decode ticks instead of re-uploading
+                if self._tables_dev is None:
+                    self._tables_dev = self._dev(self.tables)
+                used = self._blocks_used(self.pos)
+        with spans.span(spans.TICK_STEP):
             with self._mesh_ctx():
-                logits, self.pool = self._decode_paged(
-                    self.params, self.pool, self._tables_dev,
-                    toks[:, None], pos, self._blocks_used(self.pos))
-            logits = logits[:, 0]
-        else:
-            with self._mesh_ctx():
-                logits, self.cache = self._decode(self.params, self.cache,
-                                                  toks, pos)
-        nxt = self._sample(logits, self.slot_req)
+                if self.paged:
+                    logits, self.pool = self._decode_paged(
+                        self.params, self.pool, self._tables_dev,
+                        toks[:, None], pos, used, True)
+                else:
+                    logits, self.cache = self._decode(
+                        self.params, self.cache, toks, pos)
+        with spans.span(spans.TICK_SAMPLE):
+            if self.paged:
+                logits = logits[:, 0]
+            nxt = self._sample(logits, self.slot_req)
         self.ticks += 1
-        for s, req in enumerate(self.slot_req):
-            if req is None:
-                continue
-            self.pos[s] += 1
-            tok = int(nxt[s])
-            req.output.append(tok)
-            self.last_tok[s] = tok
-            if self.on_token:
-                self.on_token(req, tok)
-            if req.eos_id is not None and tok == req.eos_id:
-                req.finish_reason = "eos"
-            elif len(req.output) >= req.max_new_tokens:
-                req.finish_reason = "length"
-            elif self.pos[s] >= self.max_len - 1:
-                # context wall: out of cache positions with new-token
-                # budget left — distinguishable from natural completion
-                req.finish_reason = "truncated"
-            if req.finish_reason is not None:
-                req.done = True
-                self._evict(s)
-                if self.on_finish:
-                    self.on_finish(req)
+        self.slot_ticks += int(active.sum())
+        with spans.span(spans.TICK_EMIT):
+            for s, req in enumerate(self.slot_req):
+                if req is None:
+                    continue
+                self.pos[s] += 1
+                tok = int(nxt[s])
+                req.output.append(tok)
+                self.last_tok[s] = tok
+                if self.on_token:
+                    self.on_token(req, tok)
+                if req.eos_id is not None and tok == req.eos_id:
+                    req.finish_reason = "eos"
+                elif len(req.output) >= req.max_new_tokens:
+                    req.finish_reason = "length"
+                elif self.pos[s] >= self.max_len - 1:
+                    # context wall: out of cache positions with new-token
+                    # budget left — distinguishable from natural
+                    # completion
+                    req.finish_reason = "truncated"
+                if req.finish_reason is not None:
+                    req.done = True
+                    self._evict(s)
+                    if self.on_finish:
+                        self.on_finish(req)
 
     # --------------------------------------------------------------- run
     def run(self, requests: list[Request], max_ticks: int = 10_000

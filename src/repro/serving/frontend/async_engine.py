@@ -34,6 +34,7 @@ import queue
 import threading
 import time
 
+from repro.serving import spans
 from repro.serving.engine import Engine, Request
 from repro.serving.frontend.metrics import ServingMetrics
 from repro.serving.frontend.scheduler import SLOScheduler, Ticket
@@ -68,7 +69,8 @@ class AsyncEngine:
     """Thread-pumped asyncio front end over a (sync) ``Engine``.
 
     ``scheduler`` defaults to ``SLOScheduler``; pass ``FIFOScheduler()``
-    for the non-preemptive baseline. ``idle_wait`` is how long the pump
+    for the non-preemptive baseline. It stamps admissions on its own
+    clock, which must be ``clock``. ``idle_wait`` is how long the pump
     blocks on the submission queue when no slot is active (it never
     busy-spins an idle engine).
     """
@@ -76,8 +78,12 @@ class AsyncEngine:
     def __init__(self, engine: Engine, scheduler=None, *,
                  clock=time.monotonic, idle_wait: float = 0.002):
         self.engine = engine
-        self.scheduler = scheduler if scheduler is not None \
-            else SLOScheduler(clock=clock)
+        if scheduler is None:
+            scheduler = SLOScheduler(clock=clock)
+        elif scheduler.clock is not clock:
+            raise ValueError("the scheduler's clock must be the front "
+                             "end's: both stamp a request's metrics")
+        self.scheduler = scheduler
         self.metrics = ServingMetrics(clock=clock)
         self.idle_wait = idle_wait
         self._inbox: queue.SimpleQueue = queue.SimpleQueue()
@@ -186,26 +192,28 @@ class AsyncEngine:
         try:
             while True:
                 self._drain_inbox()
-                rep = self.scheduler.step(eng)
+                with spans.span(spans.PUMP_SCHEDULE):
+                    rep = self.scheduler.step(eng)
                 for t in rep.admitted:
-                    self.metrics.admitted(t.req.rid)
+                    self.metrics.admitted(t.req.rid, t.admit_started)
                 for t in rep.preempted:
                     self.metrics.preempted(t.req.rid)
                 if any(r is not None for r in eng.slot_req):
                     eng.tick()
-                    self.metrics.tick_gauges(eng)
                     continue
                 if len(self.scheduler):
                     # queued but unadmittable with an idle engine — a
                     # transient (e.g. radix eviction lands next step);
                     # the sleep keeps a pathological state from pegging
                     # a core
-                    time.sleep(self.idle_wait)
+                    with spans.span(spans.PUMP_WAIT):
+                        time.sleep(self.idle_wait)
                     continue
                 if self._stop.is_set() and self._inbox.empty():
                     return
                 try:                    # idle: block for new work
-                    ticket = self._inbox.get(timeout=self.idle_wait)
+                    with spans.span(spans.PUMP_WAIT):
+                        ticket = self._inbox.get(timeout=self.idle_wait)
                 except queue.Empty:
                     continue
                 self.scheduler.submit(ticket)

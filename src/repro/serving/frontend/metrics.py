@@ -3,10 +3,11 @@
 The async front end is only worth having if its latency story is
 measurable: TTFT (time to first token — the SLO the scheduler trades
 on), inter-token latency, queue wait, and preemption counts per
-request, plus engine-level gauges sampled every tick (active slots,
-free blocks, radix-cache residency/hit rate). Everything is plain host
-floats fed by the engine's ``on_token``/``on_finish`` hooks and the
-scheduler's step report — the jitted serving path is untouched.
+request, plus engine-level gauges read off the engine's own counters
+at snapshot time (ticks, active slots, free blocks, radix-cache
+residency/hit rate). Everything is plain host floats fed by the
+engine's ``on_token``/``on_finish`` hooks and the scheduler's step
+report — the jitted serving path is untouched.
 
 ``snapshot()`` exports one JSON-able dict (``launch/serve.py`` prints
 it; ``benchmarks/serving_async.py`` gates on it).
@@ -23,7 +24,7 @@ class RequestMetrics:
     injected clock; derived durations via the properties)."""
     rid: int
     submitted: float
-    admitted: float | None = None      # first admission
+    admitted: float | None = None      # start of the first admission
     first_token: float | None = None
     finished: float | None = None
     finish_reason: str | None = None
@@ -40,7 +41,9 @@ class RequestMetrics:
 
     @property
     def queue_wait(self) -> float | None:
-        """Submit -> first admission (pure scheduler delay)."""
+        """Submit -> start of the first admission (pure scheduler
+        delay: neither its own prefill nor that of a request admitted
+        before it in the same scheduler step)."""
         if self.admitted is None:
             return None
         return self.admitted - self.submitted
@@ -65,7 +68,8 @@ def _percentile(values: list[float], q: float) -> float | None:
 
 
 class ServingMetrics:
-    """Aggregator: one ``RequestMetrics`` per rid + engine gauges.
+    """Aggregator: one ``RequestMetrics`` per rid; engine gauges come
+    from the engine passed to ``snapshot``.
 
     ``clock`` is injectable for deterministic tests; production uses
     ``time.monotonic``.
@@ -75,12 +79,6 @@ class ServingMetrics:
         self.clock = clock
         self.requests: dict[int, RequestMetrics] = {}
         self.preemptions = 0           # engine-wide counter
-        self.ticks = 0
-        # gauge aggregates (sampled per tick)
-        self._active_sum = 0
-        self._active_max = 0
-        self._free_blocks_last = None
-        self._pinned_last = None
 
     # ----------------------------------------------------------- events
     def submitted(self, rid: int) -> RequestMetrics:
@@ -88,10 +86,12 @@ class ServingMetrics:
         self.requests[rid] = m
         return m
 
-    def admitted(self, rid: int):
+    def admitted(self, rid: int, started: float | None = None):
+        """``rid`` was admitted; its admission started at ``started``
+        (default: now)."""
         m = self.requests.get(rid)
         if m is not None and m.admitted is None:
-            m.admitted = self.clock()
+            m.admitted = self.clock() if started is None else started
 
     def token(self, rid: int):
         m = self.requests.get(rid)
@@ -115,21 +115,13 @@ class ServingMetrics:
             m.finished = self.clock()
             m.finish_reason = reason
 
-    def tick_gauges(self, engine):
-        """Sample engine-level gauges after one tick."""
-        self.ticks += 1
-        active = sum(r is not None for r in engine.slot_req)
-        self._active_sum += active
-        self._active_max = max(self._active_max, active)
-        if engine.paged:
-            self._free_blocks_last = engine.allocator.num_free
-            self._pinned_last = engine.allocator.num_pinned
-
     # ---------------------------------------------------------- exports
     def snapshot(self, engine=None) -> dict:
         """One JSON-able dict: latency percentiles (seconds), totals,
-        and the latest gauges (plus radix stats when the engine has the
-        cache attached)."""
+        and the engine's gauges (plus radix stats when the engine has
+        the cache attached). The tick gauges are the engine's totals
+        since its construction: ``active_mean`` is active slots per
+        tick, ``active_max`` the most slots ever active at once."""
         done = [m for m in self.requests.values()
                 if m.finished is not None]
         ttfts = [m.ttft for m in done if m.ttft is not None]
@@ -162,15 +154,20 @@ class ServingMetrics:
                  "finish_reason": m.finish_reason}
                 for m in sorted(self.requests.values(),
                                 key=lambda m: m.rid)],
-            "gauges": {
-                "ticks": self.ticks,
-                "active_mean": (self._active_sum / self.ticks
-                                if self.ticks else 0.0),
-                "active_max": self._active_max,
-                "free_blocks": self._free_blocks_last,
-                "pinned_blocks": self._pinned_last,
-            },
+            "gauges": _gauges(engine),
         }
         if engine is not None and getattr(engine, "radix", None) is not None:
             out["radix"] = engine.radix.stats()
         return out
+
+
+def _gauges(engine) -> dict:
+    ticks = engine.ticks if engine is not None else 0
+    paged = engine is not None and engine.paged
+    return {
+        "ticks": ticks,
+        "active_mean": engine.slot_ticks / ticks if ticks else 0.0,
+        "active_max": engine.peak_active if engine is not None else 0,
+        "free_blocks": engine.allocator.num_free if paged else None,
+        "pinned_blocks": engine.allocator.num_pinned if paged else None,
+    }

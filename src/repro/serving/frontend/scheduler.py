@@ -46,6 +46,7 @@ class Ticket:
     arrival: float = 0.0
     seq: int = 0                    # submission order tiebreak
     preemptions: int = 0
+    admit_started: float | None = None  # latest admission's start
 
 
 @dataclasses.dataclass
@@ -57,13 +58,18 @@ class StepReport:
 
 
 class FIFOScheduler:
-    """Arrival order, head-only, non-preemptive — the sync baseline."""
+    """Arrival order, head-only, non-preemptive — the sync baseline.
+
+    ``clock`` stamps when each admission starts (``Ticket.
+    admit_started``); injectable for deterministic tests.
+    """
 
     preemptive = False
 
-    def __init__(self):
+    def __init__(self, *, clock=time.monotonic):
         self.pending: deque[Ticket] = deque()
         self.running: dict[int, Ticket] = {}
+        self.clock = clock
 
     def submit(self, ticket: Ticket):
         self.pending.append(ticket)
@@ -71,10 +77,17 @@ class FIFOScheduler:
     def __len__(self):
         return len(self.pending)
 
-    def _note_admitted(self, t: Ticket, rep: StepReport):
+    def _admit(self, engine: Engine, t: Ticket, rep: StepReport) -> bool:
+        """Try to admit ``t`` now; on success stamp when the admission
+        started and report it."""
+        started = self.clock()
+        if not engine.admit(t.req):
+            return False
+        t.admit_started = started
         rep.admitted.append(t)
         if not t.req.done:             # admission itself may finish it
             self.running[t.req.rid] = t
+        return True
 
     def note_finished(self, req: Request):
         self.running.pop(req.rid, None)
@@ -82,11 +95,9 @@ class FIFOScheduler:
     def step(self, engine: Engine) -> StepReport:
         rep = StepReport()
         while self.pending and engine._free_slot() is not None:
-            t = self.pending[0]
-            if not engine.admit(t.req):
+            if not self._admit(engine, self.pending[0], rep):
                 break                   # head blocked: FIFO waits
             self.pending.popleft()
-            self._note_admitted(t, rep)
         return rep
 
 
@@ -101,10 +112,9 @@ class SLOScheduler(FIFOScheduler):
     def __init__(self, *, scan_limit: int = 8,
                  max_preemptions_per_step: int = 1,
                  clock=time.monotonic):
-        super().__init__()
+        super().__init__(clock=clock)
         self.scan_limit = scan_limit
         self.max_preemptions_per_step = max_preemptions_per_step
-        self.clock = clock
 
     @staticmethod
     def _key(t: Ticket):
@@ -126,10 +136,8 @@ class SLOScheduler(FIFOScheduler):
         pend = self.pending
         while i < len(pend) and scanned < self.scan_limit \
                 and engine._free_slot() is not None:
-            t = pend[i]
-            if engine.admit(t.req):
+            if self._admit(engine, pend[i], rep):
                 del pend[i]
-                self._note_admitted(t, rep)
             else:
                 i += 1
                 scanned += 1
@@ -153,7 +161,6 @@ class SLOScheduler(FIFOScheduler):
             victim.preemptions += 1
             pend.append(victim)
             rep.preempted.append(victim)
-            if engine.admit(urgent.req):
+            if self._admit(engine, urgent, rep):
                 pend.remove(urgent)
-                self._note_admitted(urgent, rep)
         return rep

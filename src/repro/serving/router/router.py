@@ -97,7 +97,7 @@ class FusedReplica:
 
 class _AllocatorView:
     """Aggregate block gauges over every replica allocator — what
-    ``ServingMetrics.tick_gauges`` reads off the router."""
+    ``ServingMetrics.snapshot`` reads off the router."""
 
     def __init__(self, allocators: Sequence):
         self._allocs = list(allocators)
@@ -158,6 +158,7 @@ class ReplicaRouter:
                 eng.on_token = self._fwd_token
                 eng.on_finish = self._fwd_finish
         self.ticks = 0                  # router steps
+        self.peak_active = 0            # most slots active at once
         self.serial_time = 0.0          # sum of replica busy seconds
         self.modeled_time = 0.0         # sum of per-step max busy
         self._busy_prev = [rep.busy_s for rep in self.replicas]
@@ -236,8 +237,13 @@ class ReplicaRouter:
         self.check_servable(req)
         for idx in self.policy.rank(self, req):
             if self.replicas[idx].admit(req):
+                self._note_active()
                 return True
         return False
+
+    def _note_active(self):
+        self.peak_active = max(self.peak_active,
+                               sum(r is not None for r in self.slot_req))
 
     def admit_from(self, pending: list[Request]) -> int:
         """Engine-compatible bounded head-of-line scan over
@@ -272,6 +278,7 @@ class ReplicaRouter:
         split described in the module docstring."""
         for rep in self.replicas:
             rep.step()
+        self._note_active()
         deltas = []
         for i, rep in enumerate(self.replicas):
             deltas.append(rep.busy_s - self._busy_prev[i])
@@ -310,6 +317,12 @@ class ReplicaRouter:
     @property
     def preemptions(self) -> int:
         return sum(e.preemptions for e in self.engines)
+
+    @property
+    def slot_ticks(self) -> int:
+        """Active slots summed over every engine's ticks: over router
+        steps (``ticks``), the fleet's mean active slots per step."""
+        return sum(e.slot_ticks for e in self.engines)
 
     def pool_bytes_per_device(self) -> int:
         return max(e.pool_bytes_per_device() for e in self.engines)

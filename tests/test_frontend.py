@@ -184,6 +184,48 @@ def test_metrics_fake_clock_accounting():
     assert detail["finish_reason"] == "length"
 
 
+class _SlowAdmit:
+    """Fake engine whose every admission (its prefill) takes 1 s on the
+    shared fake clock."""
+
+    def __init__(self, t):
+        self.t = t
+        self.slot_req = [None, None]
+
+    def _free_slot(self):
+        return 0
+
+    def admit(self, req):
+        self.t[0] += 1.0
+        return True
+
+
+@pytest.mark.parametrize("mk_sched", [FIFOScheduler, SLOScheduler],
+                         ids=["fifo", "slo"])
+def test_queue_wait_ends_where_admission_starts(mk_sched):
+    """Two requests admitted in one scheduler step: each one's queue
+    wait ends when its own admission starts, so the second's includes
+    the first's prefill but neither includes its own."""
+    t = [0.0]
+    sched = mk_sched(clock=lambda: t[0])
+    m = ServingMetrics(clock=lambda: t[0])
+    for rid in (0, 1):
+        m.submitted(rid)
+        sched.submit(Ticket(req=Request(rid=rid, tokens=[1]), seq=rid))
+    t[0] = 2.0
+    rep = sched.step(_SlowAdmit(t))
+    assert t[0] == 4.0
+    for tk in rep.admitted:
+        m.admitted(tk.req.rid, tk.admit_started)
+    assert [m.requests[r].queue_wait for r in (0, 1)] == [2.0, 3.0]
+
+
+def test_front_end_refuses_a_scheduler_on_another_clock():
+    with pytest.raises(ValueError, match="clock"):
+        AsyncEngine(_SlowAdmit([0.0]),
+                    scheduler=FIFOScheduler(clock=lambda: 0.0))
+
+
 # ------------------------------------------------------------- integration
 
 def _mk_model(**over):
@@ -375,3 +417,22 @@ def test_serve_launcher_stream_smoke(setup, capsys, monkeypatch):
     serve.main()
     out = capsys.readouterr().out
     assert "[serve] metrics:" in out and '"ttft_s"' in out
+
+
+def test_snapshot_gauges_read_the_engine(setup):
+    """The tick gauges are the engine's own counts, not a second tally
+    kept by the front end."""
+    eng = _engine(setup)
+
+    async def go():
+        async with AsyncEngine(eng) as srv:
+            streams = [srv.submit(r) for r in _reqs(3)]
+            for s in streams:
+                await s.collect()
+            return srv.metrics.snapshot(eng)
+
+    g = asyncio.run(go())["gauges"]
+    assert g["ticks"] == eng.ticks > 0
+    assert g["active_mean"] == eng.slot_ticks / eng.ticks
+    assert 1 <= g["active_mean"] <= g["active_max"] == eng.peak_active == 2
+    assert g["free_blocks"] == eng.allocator.num_free

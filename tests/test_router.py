@@ -374,6 +374,42 @@ def test_async_engine_streams_over_router(mp):
     assert asyncio.run(go()) == ref
 
 
+class _Pinned:
+    """Placement policy that ranks one chosen replica first."""
+
+    def __init__(self):
+        self.first = 0
+
+    def rank(self, router, req):
+        n = len(router.replicas)
+        return [(self.first + k) % n for k in range(n)]
+
+
+def test_router_gauges_count_the_fleet(mp):
+    """``ServingMetrics`` reads a router's gauges as it reads one
+    engine's: slot-ticks summed over the engines, over the router's own
+    steps, and the most slots the fleet held at once. The replicas peak
+    at different steps, so their own peaks add up to more."""
+    from repro.serving.frontend import ServingMetrics
+
+    model, params = mp
+    policy = _Pinned()
+    router = ReplicaRouter(
+        [FusedReplica(Engine(model, params, max_slots=2, **ENGINE_KW))
+         for _ in range(2)], policy=policy)
+    for first, rid0 in ((0, 0), (1, 10)):
+        policy.first = first
+        router.run(_reqs(n=2, rid0=rid0, max_new=3))
+    engines = router.engines
+    assert [e.peak_active for e in engines] == [2, 2]
+    assert router.peak_active == 2
+    assert router.slot_ticks == sum(e.slot_ticks for e in engines) > 0
+    g = ServingMetrics().snapshot(router)["gauges"]
+    assert g["ticks"] == router.ticks
+    assert g["active_mean"] == router.slot_ticks / router.ticks
+    assert g["active_max"] == router.peak_active
+
+
 # ---------------------------------------------------------- mesh parsing
 def test_parse_mesh_spec_named_axes():
     assert _parse_mesh_spec("2x4") == (2, 4)
