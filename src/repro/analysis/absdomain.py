@@ -11,16 +11,18 @@ Two cooperating abstractions over BlockSpec index-map arithmetic:
   the coefficients are sound, and write-once coverage can be decided in
   closed form instead of by enumeration.
 
-* **Interval / symbolic values** — for the ``paged_attention`` gather the
-  map indexes scalar-prefetch tables, which is not affine in the grid.
-  ``Sym``/``ScalarLoad``/``GatherLoad`` model grid indices and table
-  reads symbolically; comparisons build ``Guard`` records instead of
-  booleans, and ``where`` implements the ONE select pattern we accept as
-  proof of the null-block redirect: ``where(j < used[b], tables[b, j],
-  const)``. A gathered table entry is only trusted to lie in the live
-  range ``[0, NB)`` when the guard is *exactly* the liveness predicate
-  for that same (row, col) — i.e. the engine never asks for a dead
-  entry. Any other shape of select degrades soundly to the hull of the
+* **Interval / symbolic values** — the ``paged_attention`` maps index
+  scalar-prefetch step arrays and the block table, which is not affine
+  in the grid. ``Sym``/``ScalarLoad``/``GatherLoad`` model step indices
+  and table reads symbolically (``RunTable`` a sequence-major step ->
+  row array, ``RunStart`` its runs' first steps); comparisons build
+  ``Guard`` records instead of booleans, and ``where`` implements the
+  ONE select pattern we accept as proof of the null-block redirect:
+  ``where(j < used[b], tables[b, j], const)``. A gathered table entry
+  is only trusted to lie in the live range ``[0, NB)`` when the guard
+  is *exactly* the liveness predicate for that same (row, col), over a
+  column that cannot be negative — i.e. the engine never asks for a
+  dead entry. Any other shape of select degrades soundly to the hull of the
   full int32 range, which the in-bounds proof then rejects.
 
 Everything here is pure Python over ints — no jax import — so the
@@ -82,6 +84,10 @@ class Interval:
         if self.lo >= 0:
             return Interval(0, min(self.hi, o.hi - 1))
         return Interval(-(o.hi - 1), o.hi - 1)
+
+    def __lt__(self, other):
+        # a derived index compared, as in a liveness test: unknown truth
+        return Guard("lt", self, other)
 
     def hull(self, other: "Interval") -> "Interval":
         return Interval(min(self.lo, other.lo), max(self.hi, other.hi))
@@ -164,6 +170,16 @@ class Sym(_SymBase):
     def to_interval(self) -> Interval:
         return Interval(self.lo, self.hi)
 
+    def __sub__(self, other):
+        # the step's offset inside its run: i - start[runs[i]]
+        if (isinstance(other, ScalarLoad)
+                and isinstance(other.table, RunStart)
+                and isinstance(other.idx, ScalarLoad)
+                and other.idx.table is other.table.runs
+                and other.idx.idx is self):
+            return Sym(f"{self.name}-{other!r}", 0, other.table.width - 1)
+        return super().__sub__(other)
+
     def __repr__(self):
         return f"{self.name}∈[{self.lo},{self.hi}]"
 
@@ -182,6 +198,28 @@ class ScalarTable:
 
     def __repr__(self):
         return f"ScalarTable({self.name}, [{self.lo},{self.hi}])"
+
+
+class RunTable(ScalarTable):
+    """A step -> row scalar-prefetch vector in sequence-major order
+    (the paged kernel's ``seq_of_step``): non-decreasing over the steps
+    and taking every value of [lo, hi], so each row owns one contiguous
+    run of steps. An output map that reads it at the grid step writes
+    each row's block once, on consecutive steps."""
+
+
+class RunStart(ScalarTable):
+    """The first step of each row's run in a ``RunTable`` (the paged
+    kernel's ``start``), each run at most ``width`` steps long. So
+    ``i - start[runs[i]]``, a step's offset inside its own run (the
+    paged kernel's logical block), lies in [0, width): ``Sym`` models
+    that difference as a symbol of its own."""
+
+    def __init__(self, name: str, runs: RunTable, width: int, lo: int,
+                 hi: int):
+        super().__init__(name, lo, hi)
+        self.runs = runs
+        self.width = width
 
 
 @dataclasses.dataclass(frozen=True, eq=False)
@@ -235,9 +273,11 @@ class GatherLoad(_SymBase):
 
 def _is_liveness_guard(cond: Guard, load: GatherLoad) -> bool:
     """Is ``cond`` exactly ``load.col < used[load.row]`` for the used
-    table the gather table itself was declared with?"""
+    table the gather table itself was declared with, over a column
+    that cannot be negative?"""
     return (cond.op == "lt"
             and cond.lhs is load.col
+            and as_interval(load.col).lo >= 0
             and isinstance(cond.rhs, ScalarLoad)
             and cond.rhs.table is load.table.used
             and cond.rhs.idx is load.row)

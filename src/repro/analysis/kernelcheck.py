@@ -6,9 +6,11 @@ planner-reachable (config, layout, quantization, mesh-extent) workload:
 
   1. **in-bounds access** — every index map's block coordinates land
      inside the operand's block grid for ALL grid points; the
-     ``paged_attention`` table gather is modeled symbolically (live
-     entries in ``[0, num_blocks)``, the ``j >= blocks_used[b]`` →
-     null-block-0 redirect recognized explicitly, everything else
+     ``paged_attention`` maps read its scalar-prefetch step arrays and
+     block table, modeled symbolically: the step's sequence in
+     ``[0, B)``, its logical block in ``[0, nbk)``, and the table gather
+     (live entries in ``[0, num_blocks)``, the ``j >= blocks_used[b]``
+     → null-block-0 redirect recognized explicitly, everything else
      degrading to full int32 and failing).
   2. **write-once coverage** — output BlockSpecs tile the output exactly
      once: no overlapping/revisiting writes across separated grid steps,
@@ -16,8 +18,10 @@ planner-reachable (config, layout, quantization, mesh-extent) workload:
      output coordinate a distinct grid axis with unit coefficient, and
      every ignored grid axis iterating INSIDE the varying ones, so
      revisits are consecutive — TPU grids are sequential, last axis
-     fastest); anything else falls back to bounded enumeration with
-     witness grid points.
+     fastest); a map through a sequence-major step -> row array
+     (``absdomain.RunTable``) covering the output's rows writes each
+     once, on consecutive steps; anything else falls back to bounded
+     enumeration with witness grid points.
   3. **VMEM pipeline fit** — a double-buffer-aware working-set model:
      2x bytes for every block whose index map moves across the grid
      (Pallas prefetches the next block while computing on the current),
@@ -256,6 +260,38 @@ def _write_once_enumerate(spec, blk, nb) -> list:
     return out
 
 
+def _write_once_runs(spec, blk, nb) -> list:
+    """Write-once for an output map that reads scalar-prefetch arrays:
+    provable when one coordinate is a ``RunTable`` read at the grid
+    step whose rows are exactly that dim's blocks, and every other
+    coordinate pins a dim of one block."""
+    coords = blk.abstract_eval(spec.grid)
+    runs = [d for d, c in enumerate(coords)
+            if isinstance(c, absdomain.ScalarLoad)
+            and isinstance(c.table, absdomain.RunTable)]
+    if len(runs) != 1:
+        return [
+            f"{spec.tag} {blk.name}: output index map reads no sequence-"
+            f"major step -> row array (or more than one) — write-once "
+            f"coverage not provable."]
+    out = []
+    for d, c in enumerate(coords):
+        if d == runs[0]:
+            rows = (c.table.lo, c.table.hi)
+            if rows != (0, nb[d] - 1):
+                out.append(
+                    f"{spec.tag} {blk.name}: step -> row array "
+                    f"{c.table.name} covers rows {list(rows)} but output "
+                    f"dim {d} has blocks [0, {nb[d] - 1}] — holes or "
+                    f"writes out of range.")
+        elif not (isinstance(c, int) and nb[d] == 1 and c == 0):
+            out.append(
+                f"{spec.tag} {blk.name}: output dim {d} coordinate {c!r} "
+                f"over {nb[d]} blocks is not pinned to its only block — "
+                f"write-once coverage not provable.")
+    return out
+
+
 def check_write_once(spec: KernelSpec) -> list[str]:
     out = []
     for blk in spec.blocks:
@@ -264,6 +300,9 @@ def check_write_once(spec: KernelSpec) -> list[str]:
         nb = _block_grid(blk)
         if nb is None:
             continue                    # reported by check_in_bounds
+        if blk.abstract_eval is not None:
+            out.extend(_write_once_runs(spec, blk, nb))
+            continue
         try:
             forms = _affine_forms(blk, spec.grid)
         except NotAffine:
@@ -456,23 +495,28 @@ def bitplane_spec(N, M, D, block_n: int = 64,
         workload=f"N={N} M={M} D={D}")
 
 
-def _gather_eval(num_blocks: int):
-    """abstract_eval for the paged gather: grid symbols + symbolic
-    scalar-prefetch tables through the kernel's OWN index map, with the
-    abstract ``where`` injected in place of ``jnp.where``."""
-    from repro.kernels.paged_attention import kernel as k
+def _step_eval(index_map, num_blocks: int, B: int, nbk: int):
+    """abstract_eval for a paged map over the flattened grid: the grid
+    step and symbolic scalar-prefetch refs through the kernel's OWN
+    index map, with the abstract ``where`` injected in place of
+    ``jnp.where`` where the map takes one. ``seq_of_step`` is a
+    ``RunTable`` and ``start`` its ``RunStart`` (``step_arrays``' order:
+    each sequence one contiguous run of at most nbk steps)."""
+    import inspect
 
     def ev(grid):
-        B, nbk = grid
-        b = absdomain.Sym("b", 0, B - 1)
-        j = absdomain.Sym("j", 0, nbk - 1)
+        (steps,) = grid
+        seq = absdomain.RunTable("seq_of_step", 0, B - 1)
         # the wrapper clips blocks_used to [1, nbk]
         used = absdomain.ScalarTable("blocks_used", 1, nbk)
-        qpos = absdomain.ScalarTable("qpos", 0, absdomain.INT32_MAX)
-        win = absdomain.ScalarTable("win", 0, absdomain.INT32_MAX)
-        tables = absdomain.GatherTable("tables", num_blocks, used)
-        return k.block_index_map(b, j, tables, used, qpos, win,
-                                 _where=absdomain.where)
+        kw = ({"_where": absdomain.where} if "_where" in
+              inspect.signature(index_map).parameters else {})
+        return index_map(
+            absdomain.Sym("i", 0, steps - 1), seq,
+            absdomain.RunStart("start", seq, nbk, 0, steps - 1), used,
+            absdomain.GatherTable("tables", num_blocks, used),
+            absdomain.ScalarTable("qpos", 0, absdomain.INT32_MAX),
+            absdomain.ScalarTable("win", 0, absdomain.INT32_MAX), **kw)
     return ev
 
 
@@ -496,19 +540,24 @@ def paged_spec(operands: dict, *, B: int, n: int, NB: int, BS: int,
     H, n_, dv = q.shape[1], q.shape[2], (
         operands["v_pool"].shape[3] if operands.get("v_pool") is not None
         else operands["wv"].shape[2])
-    gather = _gather_eval(NB)
+    # the maps that read the step arrays; W_V / b_V stay affine
+    stepped = (k.block_index_map, k.q_index_map, k.out_index_map)
+
+    def step_eval(imap):
+        return _step_eval(imap, NB, B, nbk) if imap in stepped else None
     blocks = []
     for name, op, block, imap in specs:
         blocks.append(Block(
             name, tuple(op.shape), tuple(block), imap,
-            jnp.dtype(op.dtype).itemsize,
-            abstract_eval=gather if imap is k.block_index_map else None))
+            jnp.dtype(op.dtype).itemsize, abstract_eval=step_eval(imap)))
     out_struct = jax.ShapeDtypeStruct((B, H, n_, dv), jnp.float32)
     blocks.append(Block("out", out_struct.shape, (1, H, n_, dv),
-                        k.out_index_map, 4, out=True))
+                        k.out_index_map, 4, out=True,
+                        abstract_eval=step_eval(k.out_index_map)))
+    # the launched grid is the batch's live blocks: at most B * nbk
     spec = KernelSpec(
         kernel="paged_attention",
-        grid=(B, nbk),
+        grid=(B * nbk,),
         blocks=blocks,
         scratch_bytes=(2 * H * n_ + H * n_ * dv) * 4,
         workload=workload)

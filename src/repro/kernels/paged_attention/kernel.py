@@ -1,21 +1,27 @@
 """Pallas block-streamed paged decode attention kernel.
 
-The vLLM-PagedAttention dataflow on the TPU grid: one (sequence, logical
-block) program per grid step, with the block table and per-sequence
-``blocks_used`` as **scalar-prefetch** operands so the BlockSpec index
-maps gather each physical K/V-or-X block straight out of the pooled
-cache — the (B, nbk·BS, ...) logical view never materializes in HBM.
+The vLLM-PagedAttention dataflow on the TPU grid: one live (sequence,
+logical block) pair per grid step, with the step arrays built from the
+per-sequence ``blocks_used``, and the block table, as **scalar-prefetch**
+operands, so the BlockSpec index maps gather each physical K/V-or-X
+block straight out of the pooled cache — the (B, nbk·BS, ...) logical
+view never materializes in HBM.
 
-Early exit past a sequence's live length is two-level, mirroring the
-paper's skip hierarchy (§III.C — skip whole all-zero structures first):
+The grid is flattened to the batch's live blocks, the block-granular
+form of the paper's skip hierarchy (§III.C — whole all-zero structures
+are never visited): it has ``sum(clip(blocks_used, 1, nbk))`` steps, a
+traced bound, in sequence-major order. Step ``i`` belongs to sequence
+``b = seq_of_step[i]``, whose steps run from ``start[b]``
+(``step_arrays``), and reads the table entry of its logical block
+``i - start[b]`` (``block_index_map``). No step past a sequence's live
+length exists, so there is nothing to skip; a slot with no live block
+(``blocks_used`` 0) still gets one step, so every output block is
+written. Consecutive steps of one sequence revisit its ``q`` and
+``out`` blocks: ``q`` is fetched and ``out`` written back once per
+sequence.
 
-  * the index map redirects blocks ``j >= blocks_used[b]`` to physical
-    block 0 (the engine's null block), so the pipeline never fetches
-    dead cache lines, and
-  * ``pl.when(j < blocks_used[b])`` skips their compute entirely.
-
-Within a live block the online-softmax state (m, l, acc) persists in
-VMEM scratch across the sequential j steps (same schedule as
+Within a sequence the online-softmax state (m, l, acc) persists in VMEM
+scratch across its consecutive steps (same schedule as
 kernels/flash_scores). int8 pools (the macro's 8-bit input format)
 dequantize in-kernel from their per-row scales; ``augment``/``requant``
 reproduce the folded-bias [X 1] augmentation and the W8A8 re-quantization
@@ -38,38 +44,65 @@ BIG_WINDOW = 1 << 30
 
 # --------------------------------------------------------------- index maps
 # Named module-level functions so repro.analysis.kernelcheck can import
-# and evaluate the EXACT maps the kernel runs (RA107). Grid is (b, j);
-# the four trailing args are the scalar-prefetch refs
-# (tables, used, qpos, win) Pallas passes to every index map.
+# and evaluate the EXACT maps the kernel runs (RA107). The grid is the
+# flattened step i; the six trailing args are the scalar-prefetch refs
+# (seq_of_step, start, used, tables, qpos, win) Pallas passes to every
+# index map.
 
-def block_index_map(b, j, tables_ref, used_ref, qpos_ref, win_ref,
+def step_arrays(used, nbk: int):
+    """The flattened grid's step arrays for ``used`` (B,) live blocks
+    per sequence, already clipped to [1, nbk].
+
+    Returns ``(seq_of_step, start, total)``: ``seq_of_step`` of shape
+    (B·nbk,), the most steps a batch can launch (radix forks let rows
+    share physical blocks, so ``total`` may exceed the pool); ``start``
+    (B,) the first step of each sequence; ``total`` the grid's length.
+    Steps are sequence-major, so sequence b owns steps ``start[b] ..
+    start[b] + used[b] - 1`` and its logical block is ``i - start[b]``.
+    Entries past ``total`` are padding (the last sequence). Built with
+    no gather: the kernel runs inside the layer loop, which XLA does
+    not hoist these (B·nbk,) arrays out of.
+    """
+    B = used.shape[0]
+    ends = jnp.cumsum(used)
+    step = jnp.arange(B * nbk, dtype=jnp.int32)
+    seq = jnp.minimum(jnp.searchsorted(ends, step, side="right",
+                                       method="compare_all"), B - 1)
+    return seq.astype(jnp.int32), ends - used, ends[-1]
+
+
+def block_index_map(i, seq_ref, start_ref, used_ref, tables_ref, *_refs,
                     _where=jnp.where):
-    """Physical pool block for (b, j): the table entry while live, the
-    null block (0) past the sequence's used length — the dead gather is
-    cheap and never computed on (``pl.when`` skips it).
+    """Physical pool block of step i: the table entry of its sequence's
+    logical block while live, the null block (0) past the used length.
+    Every launched step is live; the select is what the static verifier
+    proves the gather in bounds by.
 
     ``_where`` exists so the static verifier can substitute its
     abstract-domain select; Pallas always calls with the default.
     """
+    b = seq_ref[i]
+    j = i - start_ref[b]
     return (_where(j < used_ref[b], tables_ref[b, j], 0), 0, 0, 0)
 
 
-def q_index_map(b, j, *_refs):
-    """Sequence b's query block — revisited across the whole j sweep."""
-    return (b, 0, 0, 0)
+def q_index_map(i, seq_ref, *_refs):
+    """Step i's sequence's query block — revisited across its steps."""
+    return (seq_ref[i], 0, 0, 0)
 
 
-def out_index_map(b, j, *_refs):
-    """Output block (b); held in VMEM across j, written on live steps."""
-    return (b, 0, 0, 0)
+def out_index_map(i, seq_ref, *_refs):
+    """Step i's sequence's output block; held in VMEM across its steps,
+    written back when the next sequence's steps begin."""
+    return (seq_ref[i], 0, 0, 0)
 
 
-def wv_index_map(b, j, *_refs):
+def wv_index_map(i, *_refs):
     """The whole W_V tensor, stationary for every grid step."""
     return (0, 0, 0)
 
 
-def bv_index_map(b, j, *_refs):
+def bv_index_map(i, *_refs):
     """The whole b_V tensor, stationary for every grid step."""
     return (0, 0)
 
@@ -109,7 +142,8 @@ def build_specs(q, k_pool, *, v_pool=None, k_scale=None, v_scale=None,
     return specs, flags
 
 
-def _kernel(tables_ref, used_ref, qpos_ref, win_ref, *refs,
+def _kernel(seq_ref, start_ref, used_ref, tables_ref, qpos_ref, win_ref,
+            *refs,
             BS: int, G: int, Hkv: int, H: int, n: int, dv: int,
             scale: float, softcap: float, augment: bool, requant: bool,
             has_ks: bool, has_v: bool, has_vs: bool, has_wv: bool,
@@ -125,8 +159,9 @@ def _kernel(tables_ref, used_ref, qpos_ref, win_ref, *refs,
     o_ref = next(it)
     m_sc, l_sc, acc_sc = next(it), next(it), next(it)
 
-    b = pl.program_id(0)
-    j = pl.program_id(1)
+    i = pl.program_id(0)
+    b = seq_ref[i]
+    j = i - start_ref[b]                 # logical block of sequence b
 
     @pl.when(j == 0)
     def _init():
@@ -135,47 +170,46 @@ def _kernel(tables_ref, used_ref, qpos_ref, win_ref, *refs,
         acc_sc[...] = jnp.zeros_like(acc_sc)
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    @pl.when(j < used_ref[b])
-    def _compute():
-        rep = H // G
-        kdeq = _dequant_rows(
-            k_ref[0], None if ks_ref is None else ks_ref[0])
-        keff, srow = _score_k(kdeq, augment, requant)    # (BS,G,E),(BS,G)
-        q = q_ref[0].astype(jnp.float32)                 # (H, n, E)
-        s = jnp.einsum("grne,sge->grns", q.reshape(G, rep, n, -1), keff)
-        if srow is not None:
-            s = s * srow.T[:, None, None, :]
-        s = s.reshape(H, n, BS) * scale
-        if softcap:
-            s = jnp.tanh(s / softcap) * softcap
+    # every step is live: the grid holds no step past blocks_used
+    rep = H // G
+    kdeq = _dequant_rows(
+        k_ref[0], None if ks_ref is None else ks_ref[0])
+    keff, srow = _score_k(kdeq, augment, requant)    # (BS,G,E),(BS,G)
+    q = q_ref[0].astype(jnp.float32)                 # (H, n, E)
+    s = jnp.einsum("grne,sge->grns", q.reshape(G, rep, n, -1), keff)
+    if srow is not None:
+        s = s * srow.T[:, None, None, :]
+    s = s.reshape(H, n, BS) * scale
+    if softcap:
+        s = jnp.tanh(s / softcap) * softcap
 
-        idx = j * BS + jax.lax.broadcasted_iota(jnp.int32, (n, BS), 1)
-        # (n, BS) query-position grid, element-wise reads from SMEM
-        qcol = jnp.concatenate(
-            [jnp.full((1, BS), qpos_ref[b, i], jnp.int32)
-             for i in range(n)], axis=0)
-        ok = idx <= qcol
-        ok = ok & (idx > qcol - win_ref[0])
-        s = s + jnp.where(ok, 0.0, NEG_INF)[None, :, :]
+    idx = j * BS + jax.lax.broadcasted_iota(jnp.int32, (n, BS), 1)
+    # (n, BS) query-position grid, element-wise reads from SMEM
+    qcol = jnp.concatenate(
+        [jnp.full((1, BS), qpos_ref[b, r], jnp.int32)
+         for r in range(n)], axis=0)
+    ok = idx <= qcol
+    ok = ok & (idx > qcol - win_ref[0])
+    s = s + jnp.where(ok, 0.0, NEG_INF)[None, :, :]
 
-        m_prev = m_sc[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[..., None])
-        alpha = jnp.exp(m_prev - m_new)
-        l_sc[...] = l_sc[...] * alpha + jnp.sum(p, axis=-1)
-        v = _block_values(
-            kdeq, None if v_ref is None else v_ref[0],
-            None if vs_ref is None else vs_ref[0],
-            None if wv_ref is None else wv_ref[...],
-            None if bv_ref is None else bv_ref[...])     # (BS, Hkv, dv)
-        pg = p.reshape(Hkv, H // Hkv, n, BS)
-        pv = jnp.einsum("grns,sge->grne", pg, v).reshape(H, n, dv)
-        acc_sc[...] = acc_sc[...] * alpha[..., None] + pv
-        m_sc[...] = m_new
-        # write the running normalized output every live step: the last
-        # live j (== used[b]-1) leaves the final value in the buffer, so
-        # no data-dependent "final step" predicate is needed
-        o_ref[0] = acc_sc[...] / jnp.maximum(l_sc[...], 1e-30)[..., None]
+    m_prev = m_sc[...]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
+    p = jnp.exp(s - m_new[..., None])
+    alpha = jnp.exp(m_prev - m_new)
+    l_sc[...] = l_sc[...] * alpha + jnp.sum(p, axis=-1)
+    v = _block_values(
+        kdeq, None if v_ref is None else v_ref[0],
+        None if vs_ref is None else vs_ref[0],
+        None if wv_ref is None else wv_ref[...],
+        None if bv_ref is None else bv_ref[...])     # (BS, Hkv, dv)
+    pg = p.reshape(Hkv, H // Hkv, n, BS)
+    pv = jnp.einsum("grns,sge->grne", pg, v).reshape(H, n, dv)
+    acc_sc[...] = acc_sc[...] * alpha[..., None] + pv
+    m_sc[...] = m_new
+    # write the running normalized output every step: the sequence's
+    # last step (j == used[b]-1) leaves the final value in the buffer,
+    # so no data-dependent "final step" predicate is needed
+    o_ref[0] = acc_sc[...] / jnp.maximum(l_sc[...], 1e-30)[..., None]
 
 
 @functools.partial(
@@ -207,6 +241,7 @@ def paged_attend_pallas(q: jax.Array, k_pool: jax.Array,
     Hkv = v_pool.shape[2] if v_pool is not None else wv.shape[1]
     dv = v_pool.shape[3] if v_pool is not None else wv.shape[2]
     used = jnp.clip(blocks_used.astype(jnp.int32), 1, nbk)
+    seq, start, total = step_arrays(used, nbk)
     win = jnp.asarray(
         BIG_WINDOW if window is None else window).astype(jnp.int32)
     win = win.reshape(1)
@@ -220,8 +255,8 @@ def paged_attend_pallas(q: jax.Array, k_pool: jax.Array,
         _kernel, BS=BS, G=G, Hkv=Hkv, H=H, n=n, dv=dv, scale=scale,
         softcap=softcap, augment=augment, requant=requant, **flags)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(B, nbk),
+        num_scalar_prefetch=6,
+        grid=(total,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, H, n, dv), out_index_map),
         scratch_shapes=[
@@ -235,5 +270,5 @@ def paged_attend_pallas(q: jax.Array, k_pool: jax.Array,
         out_shape=jax.ShapeDtypeStruct((B, H, n, dv), jnp.float32),
         interpret=interpret,
         name="paged_attention",
-    )(tables.astype(jnp.int32), used, qpos.astype(jnp.int32), win,
-      *operands)
+    )(seq, start, used, tables.astype(jnp.int32), qpos.astype(jnp.int32),
+      win, *operands)

@@ -924,15 +924,21 @@ class Engine:
             return used * self.block_size         # early-exit bound
         return self.blocks_per_seq * self.block_size
 
-    def _blocks_used(self, last_pos: np.ndarray):
+    def _live_blocks(self, last_pos: np.ndarray):
         """Per-slot live block counts covering every position up to
-        ``last_pos`` — the streamed schedule's early-exit bound. None on
-        the gather path (the graph then materializes the full view)."""
+        ``last_pos``, on the host and clipped to [1, blocks_per_seq] —
+        the streamed schedule's early-exit bound, and the paged
+        kernel's grid steps per slot. None on the gather path (the
+        graph then materializes the full view)."""
         if self.decode_schedule != "stream":
             return None
         used = last_pos // self.block_size + 1
-        return self._dev(np.clip(used, 1, self.blocks_per_seq)
-                         .astype(np.int32))
+        return np.clip(used, 1, self.blocks_per_seq).astype(np.int32)
+
+    def _blocks_used(self, last_pos: np.ndarray):
+        """``_live_blocks`` on the device."""
+        used = self._live_blocks(last_pos)
+        return None if used is None else self._dev(used)
 
     def _decode_paged(self, params, pool, tables, tokens, pos, used,
                       tick: bool):
@@ -984,11 +990,15 @@ class Engine:
         active = np.asarray([r is not None for r in self.slot_req])
         if not active.any():
             return
+        used = self._live_blocks(self.pos) if self.paged else None
+        # kv_blocks: the grid steps the tick's paged kernel launches
+        # per layer, the sum of its clipped blocks_used
+        stats = {} if used is None else {"kv_blocks": int(used.sum())}
         with spans.span(spans.TICK, index=self.ticks,
-                        active=int(active.sum())):
-            self._tick(active)
+                        active=int(active.sum()), **stats):
+            self._tick(active, used)
 
-    def _tick(self, active: np.ndarray):
+    def _tick(self, active: np.ndarray, used):
         if self.trace is not None:
             for s, req in enumerate(self.slot_req):
                 if req is None:
@@ -1005,7 +1015,8 @@ class Engine:
                 # copy across decode ticks instead of re-uploading
                 if self._tables_dev is None:
                     self._tables_dev = self._dev(self.tables)
-                used = self._blocks_used(self.pos)
+                if used is not None:
+                    used = self._dev(used)
         with spans.span(spans.TICK_STEP):
             with self._mesh_ctx():
                 if self.paged:

@@ -12,7 +12,10 @@ together):
 
   pump.schedule      scheduler.step: admission and preemption
   pump.wait          blocked on the inbox, or the idle sleep
-  engine.tick        [index, active] one decode tick
+  engine.tick        [index, active, kv_blocks] one decode tick;
+                     kv_blocks (streamed paged engines): the grid steps
+                     its paged kernel launches per layer, each slot's
+                     live kv blocks, at least one per slot
     tick.upload      last tokens, positions, live blocks (and the block
                      tables when they changed) to the device
     tick.step        dispatch of the tick program (returns before the

@@ -423,23 +423,68 @@ def test_kernelcheck_gather_unguarded_escapes_bounds():
     by int32 range), so check_in_bounds must flag it."""
     import dataclasses
     kc = _kc()
-    from repro.analysis import absdomain
 
-    def unguarded(grid):
-        B, nbk = grid
-        b = absdomain.Sym("b", 0, B - 1)
-        j = absdomain.Sym("j", 0, nbk - 1)
-        used = absdomain.ScalarTable("blocks_used", 1, nbk)
-        tables = absdomain.GatherTable("tables", 64, used)
-        return (tables[b, j], 0, 0, 0)   # no `j < used[b]` redirect
+    def unguarded(i, seq_ref, start_ref, used_ref, tables_ref, *_refs):
+        b = seq_ref[i]
+        return (tables_ref[b, i - start_ref[b]], 0, 0, 0)  # no redirect
 
     spec, _ = _paged_fixture(kc)
     bad = dataclasses.replace(spec, blocks=[
-        dataclasses.replace(b, abstract_eval=unguarded)
-        if b.abstract_eval is not None else b
+        dataclasses.replace(b, abstract_eval=kc._step_eval(
+            unguarded, 64, 4, 4)) if b.name == "k_pool" else b
         for b in spec.blocks])
     out = kc.check_in_bounds(bad)
     assert any("gather" in v and "escapes" in v for v in out), out
+
+
+def test_kernelcheck_gather_off_its_own_run_escapes_bounds():
+    """A logical block measured from another sequence's first step can
+    be negative, so the liveness guard no longer proves the gather."""
+    import dataclasses
+    kc = _kc()
+    import jax.numpy as jnp
+
+    def off_run(i, seq_ref, start_ref, used_ref, tables_ref, *_refs,
+                _where=jnp.where):
+        b = seq_ref[i]
+        j = i - start_ref[0]
+        return (_where(j < used_ref[b], tables_ref[b, j], 0), 0, 0, 0)
+
+    spec, _ = _paged_fixture(kc)
+    bad = dataclasses.replace(spec, blocks=[
+        dataclasses.replace(b, abstract_eval=kc._step_eval(
+            off_run, 64, 4, 4)) if b.name == "k_pool" else b
+        for b in spec.blocks])
+    out = kc.check_in_bounds(bad)
+    assert any("gather" in v and "escapes" in v for v in out), out
+
+
+def test_kernelcheck_paged_grid_is_the_flattened_steps():
+    """The paged kernel's grid is one axis of at most B * nbk steps,
+    and the maps that read the step arrays are evaluated abstractly."""
+    kc = _kc()
+    from repro.kernels.paged_attention import kernel as k
+    spec, _ = _paged_fixture(kc)
+    assert spec.grid == (4 * 4,)
+    stepped = {b.name for b in spec.blocks if b.abstract_eval is not None}
+    assert stepped == {"q", "k_pool", "v_pool", "out"}
+    out_blk = next(b for b in spec.blocks if b.out)
+    assert out_blk.index_map is k.out_index_map
+
+
+def test_kernelcheck_paged_out_map_off_the_sequence_is_flagged():
+    """An output map that follows the step's physical block instead of
+    its sequence has no write-once proof."""
+    import dataclasses
+    kc = _kc()
+    from repro.kernels.paged_attention import kernel as k
+    spec, _ = _paged_fixture(kc)
+    bad = dataclasses.replace(spec, blocks=[
+        dataclasses.replace(b, abstract_eval=kc._step_eval(
+            k.block_index_map, 64, 4, 4)) if b.out else b
+        for b in spec.blocks])
+    out = kc.check_write_once(bad)
+    assert any("write-once coverage not provable" in v for v in out), out
 
 
 def _paged_fixture(kc, int8=False):

@@ -9,7 +9,9 @@ partition. Interpret-mode tests cannot see any of these.
 Widths are the served ones: qwen2.5-14b's paged kv pool (H=40, Hkv=8,
 dh=128, 16-token blocks, bf16) for a decode tick (n=1) and a prefill
 chunk (n=64), on one chip and head-sharded 4 ways through the
-``shard_map`` the engine uses under a mesh; and ``wqk_score`` at
+``shard_map`` the engine uses under a mesh, at a 1024-token table and
+at the served one (4096 tokens, 32 slots: a flattened grid of up to
+8,192 steps, whose step arrays Mosaic keeps in SMEM); and ``wqk_score`` at
 whisper-tiny's encoder shape (N=1500, D_aug=385, H=6).
 
 The topology is described inside a module fixture — never at import —
@@ -28,6 +30,7 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
 H, HKV, DH, BS = 40, 8, 128, 16        # qwen2.5-14b attention widths
 NB = 2048                              # pool blocks: 32k tokens
 NBK = 64                               # table width: 1024-token context
+SERVED_NBK, SERVED_SLOTS = 256, 32     # max_len 4096, the served batch
 TP = 4
 HBM_BYTES = 16 * 10 ** 9               # one v5e chip
 
@@ -64,11 +67,12 @@ def tp_mesh(topo):
                 ("data", "model"))
 
 
-def _paged_operands(n, sharding, head_sharding=None):
+def _paged_operands(n, sharding, head_sharding=None, nbk=NBK, slots=8):
     """(q, KVCache pool, tables, blocks_used, qpos) shape structs for a
-    decode tick (n=1, 8 slots) or one prefill chunk (n=64, 1 slot)."""
+    decode tick (n=1, ``slots`` slots) or one prefill chunk (n=64, 1
+    slot) over a table ``nbk`` blocks wide."""
     from repro.models.attention import KVCache
-    B = 8 if n == 1 else 1
+    B = slots if n == 1 else 1
     hs = head_sharding or sharding
 
     def sds(shape, dt, sh=sharding):
@@ -76,7 +80,7 @@ def _paged_operands(n, sharding, head_sharding=None):
     q = sds((B, H, n, DH), jnp.float32, hs)
     pool = KVCache(k=sds((NB, BS, HKV, DH), jnp.bfloat16, hs),
                    v=sds((NB, BS, HKV, DH), jnp.bfloat16, hs))
-    return (q, pool, sds((B, NBK), jnp.int32), sds((B,), jnp.int32),
+    return (q, pool, sds((B, nbk), jnp.int32), sds((B,), jnp.int32),
             sds((B, n), jnp.int32))
 
 
@@ -87,24 +91,20 @@ def _stream(q, pool, tables, used, qpos):
                            impl="pallas", interpret=False)
 
 
-@pytest.mark.parametrize("n", [1, 64])
-def test_paged_kv_kernel_compiles_on_one_chip(one_chip, n):
-    compiled = jax.jit(_stream).lower(*_paged_operands(n, one_chip)) \
-        .compile()
+def _compile_one_chip(one_chip, n, **width):
+    compiled = jax.jit(_stream).lower(
+        *_paged_operands(n, one_chip, **width)).compile()
     assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes \
         + mem.output_size_in_bytes < HBM_BYTES
 
 
-@pytest.mark.parametrize("n", [1, 64])
-def test_paged_kv_kernel_shard_maps_over_four_chips(tp_mesh, n):
-    """Heads on "model", tables replicated: each chip runs the kernel
-    on its head-slice (10 query / 2 kv heads) with no communication."""
+def _compile_four_chips(tp_mesh, n, **width):
     from repro.launch import hlo
     from repro.sharding import act
     rep = NamedSharding(tp_mesh, P())
-    args = _paged_operands(n, rep)
+    args = _paged_operands(n, rep, **width)
     q, pool = args[0], args[1]
     heads = NamedSharding(tp_mesh, P(None, "model"))
     rows = NamedSharding(tp_mesh, P(None, None, "model"))
@@ -120,6 +120,31 @@ def test_paged_kv_kernel_shard_maps_over_four_chips(tp_mesh, n):
              for k, v in c.items()}
     assert not found, f"collectives around the sharded kernel: {found}"
     assert compiled.output_shardings.spec == P(None, "model")
+
+
+@pytest.mark.parametrize("n", [1, 64])
+def test_paged_kv_kernel_compiles_on_one_chip(one_chip, n):
+    _compile_one_chip(one_chip, n)
+
+
+@pytest.mark.parametrize("n", [1, 64])
+def test_paged_kv_kernel_shard_maps_over_four_chips(tp_mesh, n):
+    """Heads on "model", tables replicated: each chip runs the kernel
+    on its head-slice (10 query / 2 kv heads) with no communication."""
+    _compile_four_chips(tp_mesh, n)
+
+
+@pytest.mark.parametrize("chips", [1, TP])
+@pytest.mark.parametrize("n", [1, 64])
+def test_paged_kv_kernel_compiles_at_the_served_width(one_chip, tp_mesh,
+                                                      chips, n):
+    """The served table (256 blocks) and batch (32 slots): the step
+    arrays of the flattened grid hold 8,192 entries per tick."""
+    width = dict(nbk=SERVED_NBK, slots=SERVED_SLOTS)
+    if chips == 1:
+        _compile_one_chip(one_chip, n, **width)
+    else:
+        _compile_four_chips(tp_mesh, n, **width)
 
 
 @pytest.mark.parametrize("shape", [(1, 1500, 385, 6), (4, 448, 385, 6)],

@@ -3,7 +3,10 @@ gather-view oracle — every cache layout (kv / x / xv, float + int8),
 the jnp while-loop reference AND the Pallas kernel (interpret mode on
 CPU), ragged per-sequence lengths, windowed masks, chunk-shaped (n>1)
 queries, and the ``blocks_used`` early exit (proved by NaN-poisoning
-the blocks past the live region: the stream must never touch them)."""
+the blocks past the live region: the stream must never touch them).
+The Pallas kernel's grid is the batch's live blocks, flattened: its
+step arrays, and tables that forks share, empty slots, a row at the
+full table width and a windowed chunk, each against the oracle."""
 import dataclasses
 
 import jax
@@ -14,6 +17,7 @@ import pytest
 from repro.configs.base import get_arch, reduced
 from repro.core import quant
 from repro.core import score_backend as sb
+from repro.kernels.paged_attention import kernel as pkernel
 from repro.kernels.paged_attention import ops as pops
 
 IMPLS = ("jnp", "pallas")
@@ -87,6 +91,13 @@ def test_x_layout_matches_gather_oracle(impl, int8, with_vpool):
     """X-consuming stream: [X 1] augmentation, per-row W8A8 requant, and
     pure-X V-recompute (the paper's weight-stationary dataflow) against
     the same math on the materialized view."""
+    _check_x_layout(impl, int8, with_vpool, lambda rng: (
+        _tables(rng), jnp.asarray(POS[:, None]), _used(POS, 1)))
+
+
+def _check_x_layout(impl, int8, with_vpool, rows):
+    """The X-layout stream against the gather oracle, over the
+    ``(tables, qpos, blocks_used)`` that ``rows(rng)`` draws."""
     rng = _rng()
     n = 1
     xf = rng.normal(size=(NB, BS, D)).astype(np.float32)
@@ -97,9 +108,7 @@ def test_x_layout_matches_gather_oracle(impl, int8, with_vpool):
     else:
         kp, ks = jnp.asarray(xf)[:, :, None, :], None
         xdeq = jnp.asarray(xf)
-    tables = _tables(rng)
-    qpos = jnp.asarray(POS[:, None])
-    used = _used(POS, n)
+    tables, qpos, used = rows(rng)
     g = jnp.asarray(rng.normal(size=(B, H, n, D + 1)), jnp.float32)
     wv = jnp.asarray(rng.normal(size=(D, Hkv, dh)), jnp.float32)
     bv = jnp.asarray(rng.normal(size=(Hkv, dh)), jnp.float32)
@@ -173,6 +182,104 @@ def test_blocks_used_early_exit_skips_dead_blocks(impl):
     assert bool(jnp.all(jnp.isfinite(got)))
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
+
+
+def _flat_case(case, rng):
+    """(tables, blocks_used, qpos, n, nbk, window) for one shape of the
+    flattened grid. ``forked``: two rows share the first physical blocks
+    of a third, as radix prefix forks make them; ``empty``: slots with no live block (the
+    wrapper clips 0 to 1, an empty slot's position is 0); ``full``: one
+    row uses the whole table; ``chunk``: one 64-token chunk (B = 1)
+    under a window."""
+    if case == "chunk":
+        nbk, n = 22, 64
+        tables = rng.permutation(np.arange(1, NB))[:nbk].reshape(1, nbk)
+        last = np.array([80])
+        window = 37
+    else:
+        nbk, n, window = NBK, 1, None
+        tables = np.array(_tables(rng))
+        last = POS.copy()
+        if case == "forked":
+            tables[1, :3] = tables[0, :3]
+            tables[2, :2] = tables[0, :2]
+        if case == "full":
+            last[1] = NBK * BS - 1
+        if case == "empty":
+            last[[0, 2]] = 0
+    used = last // BS + 1
+    if case == "empty":
+        used[[0, 2]] = 0
+    qpos = last[:, None] - n + 1 + np.arange(n)[None, :]
+    return (jnp.asarray(tables, jnp.int32), jnp.asarray(used, jnp.int32),
+            jnp.asarray(np.maximum(qpos, 0)), n, nbk, window)
+
+
+def _oracle_view(pool, tables, nbk):
+    return jnp.take(pool, tables, axis=0).reshape(
+        (tables.shape[0], nbk * BS) + pool.shape[2:])
+
+
+@pytest.mark.parametrize("case", ["forked", "empty", "full", "chunk"])
+def test_flattened_grid_matches_gather_oracle(case):
+    rng = _rng()
+    tables, used, qpos, n, nbk, window = _flat_case(case, rng)
+    b = tables.shape[0]
+    q = jnp.asarray(rng.normal(size=(b, H, n, dh)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(NB, BS, Hkv, dh)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(NB, BS, Hkv, dh)), jnp.float32)
+    kv, vv = _oracle_view(kp, tables, nbk), _oracle_view(vp, tables, nbk)
+    S = nbk * BS
+    s = jnp.einsum("bgrne,bsge->bgrns", q.reshape(b, Hkv, -1, n, dh),
+                   kv).reshape(b, H, n, S) * 0.25
+    idx = jnp.arange(S)[None, None, :]
+    ok = idx <= qpos[:, :, None]
+    if window is not None:
+        ok = ok & (idx > qpos[:, :, None] - window)
+    a = jax.nn.softmax(s + jnp.where(ok, 0.0, -1e30)[:, None], axis=-1)
+    want = jnp.einsum("bgrns,bsge->bgrne", a.reshape(b, Hkv, -1, n, S),
+                      vv).reshape(b, H, n, dh)
+    got = pops.paged_attend(q, kp, tables, used, qpos, v_pool=vp,
+                            scale=0.25, window=window, impl="pallas")
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+
+
+def test_flattened_grid_int8_x_pool_with_forks():
+    """The int8 X pool (shared stream, per-row scales, V recomputed
+    through W_V) over forked rows, an empty slot and a full row."""
+    def rows(rng):
+        tables = np.array(_tables(rng))
+        tables[2, :2] = tables[1, :2]
+        return (jnp.asarray(tables), jnp.asarray([[0], [NBK * BS - 1], [6]]),
+                jnp.asarray([0, NBK, 2], jnp.int32))
+    _check_x_layout("pallas", True, False, rows)
+
+
+def test_step_arrays_walk_the_live_blocks_in_sequence_order():
+    """As many steps as clipped live blocks (more than the pool holds,
+    for forked rows), sequence-major, the logical block restarting at 0
+    for each slot, and each step's block map on that block's entry."""
+    nbk, nb = 6, 8
+    tables = np.arange(24).reshape(4, nbk) % (nb - 1) + 1
+    tables[3] = tables[1]                          # a fork of row 1
+    used = np.array([0, 3, 6, 6])
+    clipped = np.clip(used, 1, nbk)
+    seq, start, total = (np.asarray(a) for a in pkernel.step_arrays(
+        jnp.asarray(clipped, jnp.int32), nbk))
+    assert seq.shape == (4 * nbk,)
+    assert int(total) == clipped.sum() == 16 and total > nb
+    want_seq = np.repeat(np.arange(4), clipped)
+    want_j = np.concatenate([np.arange(u) for u in clipped])
+    np.testing.assert_array_equal(seq[:total], want_seq)
+    np.testing.assert_array_equal(start, np.cumsum(clipped) - clipped)
+    np.testing.assert_array_equal(np.arange(total) - start[seq[:total]],
+                                  want_j)
+    assert (seq[total:] == 3).all()
+    blocks = [pkernel.block_index_map(i, seq, start, clipped, tables,
+                                      _where=np.where)[0]
+              for i in range(total)]
+    np.testing.assert_array_equal(blocks, tables[want_seq, want_j])
 
 
 def test_stream_q_matches_backend_scores():

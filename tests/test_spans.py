@@ -1,12 +1,14 @@
 """The serving program's profiler spans: a tiny run through the async
 front end with the profiler on names every phase, nested as
-``repro.serving.spans`` says, with the request on admission spans."""
+``repro.serving.spans`` says, with the request on admission spans and
+the paged kernel's launched kv blocks on each tick."""
 import asyncio
 import dataclasses
 import glob
 import os
 
 import jax
+import numpy as np
 import pytest
 
 from repro.configs.base import get_arch, reduced
@@ -23,14 +25,16 @@ PARENT = {
     spans.ADMIT: spans.PUMP_SCHEDULE, spans.ADMIT_RESERVE: spans.ADMIT,
     spans.ADMIT_CHUNK: spans.ADMIT, spans.ADMIT_SAMPLE: spans.ADMIT,
 }
-STATS = {spans.TICK: {"index", "active"}, spans.ADMIT: {"rid"},
+STATS = {spans.TICK: {"index", "active", "kv_blocks"},
+         spans.ADMIT: {"rid"},
          spans.ADMIT_RESERVE: {"rid"}, spans.ADMIT_CHUNK: {"rid", "c0"},
          spans.ADMIT_SAMPLE: {"rid"}}
 
 
 @pytest.fixture(scope="module")
 def traced(tmp_path_factory):
-    """(spans by name, requests): spans as (start, end, stats, line)."""
+    """(spans by name, requests, kv blocks launched per traced tick):
+    spans as (start, end, stats, line)."""
     cfg = dataclasses.replace(
         reduced(get_arch("qwen2.5-14b"), num_layers=2), dtype="float32")
     model = build_model(cfg)
@@ -39,6 +43,13 @@ def traced(tmp_path_factory):
     reqs = [Request(rid=10 + i, tokens=[1] + list(range(3, 3 + n)),
                     max_new_tokens=4) for i, n in enumerate((5, 20, 9))]
     eng.run([Request(rid=-1, tokens=[1] * 20, max_new_tokens=2)])
+    launched, step = [], eng._decode_paged
+
+    def spy(params, pool, tables, tokens, pos, used, tick):
+        if tick:                         # the blocks_used the tick runs
+            launched.append(int(np.asarray(used).sum()))
+        return step(params, pool, tables, tokens, pos, used, tick)
+    eng._decode_paged = spy
 
     async def serve():
         async with AsyncEngine(eng) as srv:
@@ -63,17 +74,17 @@ def traced(tmp_path_factory):
                     found.setdefault(e.name, []).append(
                         (e.start_ns, e.start_ns + e.duration_ns,
                          dict(e.stats), (p.name, i)))
-    return found, reqs
+    return found, reqs, launched
 
 
 def test_every_span_is_written(traced):
-    found, _ = traced
+    found, *_ = traced
     assert set(found) == set(spans.NAMES)
 
 
 @pytest.mark.parametrize("name", [n for n in spans.NAMES if PARENT[n]])
 def test_span_nests_in_its_parent(traced, name):
-    found, _ = traced
+    found, *_ = traced
     for a, b, _, line in found[name]:
         assert any(pa <= a and b <= pb and pline == line
                    for pa, pb, _, pline in found[PARENT[name]]), (name, a)
@@ -81,7 +92,7 @@ def test_span_nests_in_its_parent(traced, name):
 
 @pytest.mark.parametrize("name", sorted(STATS))
 def test_span_carries_its_stats(traced, name):
-    found, reqs = traced
+    found, reqs, _ = traced
     rids = {r.rid for r in reqs}
     for *_, stats, _ in found[name]:
         assert STATS[name] <= set(stats)
@@ -92,7 +103,7 @@ def test_span_carries_its_stats(traced, name):
 
 
 def test_tick_spans_count_the_ticks(traced):
-    found, _ = traced
+    found, *_ = traced
     ticks = found[spans.TICK]
     index = [s["index"] for *_, s, _ in ticks]
     assert index == list(range(index[0], index[0] + len(ticks)))
@@ -106,6 +117,15 @@ def test_tick_spans_count_the_ticks(traced):
                        for x in found[n] if a <= x[0] <= b and x[3] == line)
         assert [n for _, n in inner] == [spans.TICK_UPLOAD, spans.TICK_STEP,
                                          spans.TICK_SAMPLE, spans.TICK_EMIT]
+
+
+def test_tick_span_counts_the_launched_kv_blocks(traced):
+    """``kv_blocks`` on each tick is the sum of the clipped
+    ``blocks_used`` that tick hands the paged kernel: its grid steps."""
+    found, _, launched = traced
+    kv = [s["kv_blocks"] for *_, s, _ in found[spans.TICK]]
+    assert kv == launched and len(kv) >= 4
+    assert all(2 <= k <= 2 * 8 for k in kv)    # 2 slots, 8-block rows
 
 
 def test_tick_and_chunk_compile_once_each_under_their_names():
