@@ -6,10 +6,11 @@
 For each seed, in one process: weights from the seed, a window of the
 cell's traffic at its own load, then the same sample of finished
 requests that a benchmark run compares (``check.sample``), replayed
-through the float32 reference: the widest gap of a served token below
-the reference's best. With ``--control`` the reference in float8
-(``reference.py``) is put in the program's place on the same prompts
-and tokens, and the gap of its own first choices is read the same way.
+through the float32 reference of the cell's architecture: the widest
+gap of a served token below the reference's best. With ``--control``
+the reference in float8 is put in the program's place on the same
+prompts and tokens, and the gap of its own first choices is read the
+same way.
 One JSON line per seed on standard output.
 """
 from __future__ import annotations
@@ -46,7 +47,6 @@ def main(argv=None) -> int:
     chip = run.open_chip(cell, ROOT)
     if chip is None:
         return 1
-    from bench import reference
     lim = cell.config["correctness"]
     for seed in (int(x) for x in args.seeds.split(",")):
         params = run.make_weights(cell, seed)
@@ -59,9 +59,9 @@ def main(argv=None) -> int:
         rec = {"workload": cell.name, "seed": seed,
                "requests": len(picked),
                "finished": len(check.finished(r)),
-               "program": _stats(reference.gaps(cell.config, seed, seqs))}
+               "program": _stats(cell.arch.gaps(cell.config, seed, seqs))}
         if args.control:
-            rec["control_fp8"] = _stats(reference.gaps(
+            rec["control_fp8"] = _stats(cell.arch.gaps(
                 cell.config, seed, seqs, quant="fp8"))
         print(json.dumps(rec), flush=True)
     return 0

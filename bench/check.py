@@ -3,8 +3,9 @@
 Once the window has closed and the program's state is freed, a sample
 of the requests the engine finished, drawn from the seed and with the
 longest of them in it, is replayed through the plain float32 reference
-(``bench/reference.py``), teacher-forced on the served tokens. Compared,
-each against the limit in the configuration's ``correctness``:
+of the cell's architecture (its module's ``gaps``), teacher-forced on
+the served tokens. Compared, each against the limit in the
+configuration's ``correctness``:
 
   logit_gap      the widest gap, over every sampled served token, of
                  the reference's logit of that token below the
@@ -68,8 +69,7 @@ def judge(cell, seed: int, run, gap_fn=None) -> dict:
     """The verdict: ``correct``, each compared number with its limit
     (``limits``) and the lines that print them."""
     if gap_fn is None:
-        from bench import reference
-        gap_fn = reference.gaps
+        gap_fn = cell.arch.gaps
     lim = cell.config["correctness"]
     picked = sample(run, seed, lim["sample_tokens"],
                     lim["sample_max_requests"])

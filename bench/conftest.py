@@ -1,6 +1,6 @@
 """A tiny checkout for the benchmark's tests: the real ``bench/``
-metric readers and the serving program, with a small configuration
-and traffic mix, so a whole run fits a CPU test."""
+metric readers, architecture modules and the serving program, with a
+small configuration and traffic mix, so a whole run fits a CPU test."""
 from __future__ import annotations
 
 import json
@@ -14,7 +14,8 @@ from bench.spec import BENCH_DIR
 REPO = os.path.dirname(BENCH_DIR)
 
 TINY_CONFIG = {
-    "name": "tiny", "source": "test", "hidden_size": 64,
+    "name": "tiny", "source": "test", "architecture": "dense_gqa",
+    "hidden_size": 64,
     "intermediate_size": 128, "num_attention_heads": 4,
     "num_key_value_heads": 2, "num_hidden_layers": 2, "vocab_size": 512,
     "rms_norm_eps": 1e-06, "rope_theta": 10000.0, "hidden_act": "silu",
@@ -48,6 +49,13 @@ def make_root(path, config=TINY_CONFIG, traffic=TINY_TRAFFIC) -> str:
     os.makedirs(os.path.join(root, "bench", "traffic"))
     shutil.copytree(os.path.join(BENCH_DIR, "metrics"),
                     os.path.join(root, "bench", "metrics"))
+    # linked file by file: the modules load once a process, and a file
+    # a test drops in stays in its checkout
+    os.makedirs(os.path.join(root, "bench", "arch"))
+    for f in os.listdir(os.path.join(BENCH_DIR, "arch")):
+        if f.endswith(".py"):
+            os.symlink(os.path.join(BENCH_DIR, "arch", f),
+                       os.path.join(root, "bench", "arch", f))
     os.symlink(os.path.join(REPO, "src"), os.path.join(root, "src"))
     with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
         json.dump(bench, f)

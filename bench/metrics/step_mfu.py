@@ -1,8 +1,8 @@
 """Device: the decode-tick and prefill-chunk programs' share of the
 chip's peak bf16 rate: the operations the traced ticks and chunks need
-(``bench/costs.decode_tick`` and ``prefill_chunk``: active sequences
-and real prompt tokens only) over those programs' device time times
-the peak, in percent."""
+(the architecture module's ``decode_tick`` and ``prefill_chunk``:
+active sequences and real prompt tokens only) over those programs'
+device time times the peak, in percent."""
 
 
 def read(run):

@@ -2,10 +2,11 @@
 
 It builds what ``launch/serve.py`` builds (``Engine`` over the paged
 pool with the default plan, the async front end over it) from a
-configuration file, hands it the benchmark's weights, and wraps two
-methods of the engine instance, ``tick`` and ``admit``, to record when
-each ran, to name it in a profiler trace, and to stop the engine when
-the window closes. Nothing else of the program is read.
+configuration file and the model configuration that the cell's
+architecture module gives, hands it the benchmark's weights, and wraps
+two methods of the engine instance, ``tick`` and ``admit``, to record
+when each ran, to name it in a profiler trace, and to stop the engine
+when the window closes. Nothing else of the program is read.
 """
 from __future__ import annotations
 
@@ -33,32 +34,18 @@ def import_program(root: str) -> None:
         sys.path.insert(0, src)
 
 
-def model_config(config: dict):
-    from repro.configs.base import ModelConfig
-    d, h = config["hidden_size"], config["num_attention_heads"]
-    if config["hidden_act"] != "silu":
-        raise ValueError(f"hidden_act {config['hidden_act']!r}")
-    return ModelConfig(
-        name=config["name"], family="dense",
-        num_layers=config["num_hidden_layers"], d_model=d, num_heads=h,
-        num_kv_heads=config["num_key_value_heads"],
-        head_dim=config.get("head_dim", d // h),
-        d_ff=config["intermediate_size"], vocab_size=config["vocab_size"],
-        qkv_bias=bool(config["qkv_bias"]),
-        rope_theta=float(config["rope_theta"]),
-        tie_embeddings=bool(config["tie_word_embeddings"]),
-        dtype=config["torch_dtype"])
-
-
 def engine_options(config: dict, traffic: dict) -> dict:
     return {**config["engine"], **traffic.get("engine", {})}
 
 
-def build_engine(config: dict, traffic: dict, params):
+def build_engine(cell, params):
+    """The engine over ``params`` for the cell's configuration, as its
+    architecture module gives it to the program."""
     from repro.models.model import build_model
     from repro.serving.engine import Engine
-    opts = engine_options(config, traffic)
-    return Engine(build_model(model_config(config)), params,
+    opts = engine_options(cell.config, cell.traffic)
+    model = build_model(cell.arch.program_config(cell.config))
+    return Engine(model, params,
                   max_slots=opts["max_slots"], max_len=opts["max_len"],
                   block_size=opts["block_size"],
                   hbm_bytes=opts["hbm_bytes"],

@@ -1,16 +1,13 @@
-"""Plain float32 reference of the dense GQA decoder (Qwen2 / InternLM2).
+"""Pieces a plain float32 reference is built from, with no model in them.
 
-Written from the published architecture, not from the serving program,
-and importing nothing of it: token embedding; per layer RMSNorm, q/k/v
-projections (with bias where the configuration has it), rotary
-embedding (half-split, ``rotate_half``), causal attention in which
-query head h reads key/value head ``h // (heads / kv_heads)``, output
-projection, residual; RMSNorm, SiLU-gated feed-forward, residual; a
-final RMSNorm and the output head. Every contraction is float32 at the
-highest precision. It runs one layer at a time over one whole sequence,
-the layer's weights made from the seed (``bench/weights.py``) as it
-reaches them, queries in blocks, so that it fits on one chip at the
-published widths.
+Each architecture module (``bench/arch/``) writes its reference from
+the published architecture, not from the serving program, and imports
+nothing of the program. Every contraction is float32 at the highest
+precision. A reference runs one layer at a time over one whole
+sequence, padded (``padded``), its weights made from the seed
+(``bench/weights.py``) as it reaches them, queries in blocks
+(``causal_attention``), so that it fits on one chip at the published
+widths. ``served_gaps`` is the comparison every module's ``gaps`` makes.
 
 ``quant="fp8"`` is the control, the reference in the precision below
 the configuration's bfloat16: every projection's operands rounded to
@@ -24,9 +21,6 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-from bench import weights as W
-from bench.costs import Shapes
 
 HI = jax.lax.Precision.HIGHEST
 Q_BLOCK = 512          # queries per attention block
@@ -69,20 +63,21 @@ def _rope(x, pos, theta):
     return x * jnp.cos(emb) + rot * jnp.sin(emb)
 
 
-@functools.partial(jax.jit, static_argnames=("s", "eps", "theta", "quant"))
-def _layer(x, w, *, s: Shapes, eps: float, theta: float, quant):
-    """One decoder layer over a whole (padded) sequence x (T, d)."""
-    T = x.shape[0]
-    f = {k: v.astype(jnp.float32) for k, v in w.items()}
+def padded(tokens) -> np.ndarray:
+    """``tokens`` as int32, zero-padded to a multiple of ``PAD``, so few
+    sequence lengths compile."""
+    ids = np.zeros(-(-len(tokens) // PAD) * PAD, np.int32)
+    ids[:len(tokens)] = tokens
+    return ids
+
+
+def causal_attention(q, k, v, scale):
+    """Causal attention over a whole (padded) sequence, one query head
+    per key/value head: q, k, v (T, heads, dh), T a multiple of
+    ``Q_BLOCK``; queries in blocks of ``Q_BLOCK``. Returns (T, heads,
+    dh)."""
+    T = q.shape[0]
     pos = jnp.arange(T)
-    h = _rms(x, 1.0 + f["attn_norm"], eps)
-    q, k, v = (_proj(h, f[n], quant) for n in ("wq", "wk", "wv"))
-    if s.qkv_bias:
-        q, k, v = q + f["bq"], k + f["bk"], v + f["bv"]
-    q, k = _rope(q, pos, theta), _rope(k, pos, theta)
-    rep = s.heads // s.kv_heads
-    k, v = jnp.repeat(k, rep, axis=1), jnp.repeat(v, rep, axis=1)
-    scale = 1.0 / np.sqrt(s.head_dim)
 
     def block(i):
         qb = jax.lax.dynamic_slice_in_dim(q, i * Q_BLOCK, Q_BLOCK)
@@ -93,23 +88,7 @@ def _layer(x, w, *, s: Shapes, eps: float, theta: float, quant):
         p = jax.nn.softmax(sc, axis=-1)
         return jnp.einsum("hqk,khe->qhe", p, v, precision=HI)
 
-    o = jax.lax.map(block, jnp.arange(T // Q_BLOCK))
-    o = o.reshape(T, s.heads * s.head_dim)
-    x = x + _proj(o, f["wo"].reshape(-1, s.d), quant)
-    h = _rms(x, 1.0 + f["mlp_norm"], eps)
-    g = _proj(h, f["w_gate"], quant)
-    u = _proj(h, f["w_up"], quant)
-    return x + _proj(jax.nn.silu(g) * u, f["w_down"], quant)
-
-
-@functools.partial(jax.jit, static_argnames=("s",))
-def _make_layer(key, layer, *, s: Shapes):
-    return W.layer_weights(key, s, layer)
-
-
-@functools.partial(jax.jit, static_argnames=("s", "name"))
-def _make_global(key, *, s: Shapes, name: str):
-    return W.global_weight(key, s, name)
+    return jax.lax.map(block, jnp.arange(T // Q_BLOCK))
 
 
 @functools.partial(jax.jit, static_argnames=("eps", "quant"))
@@ -124,37 +103,16 @@ def _head_gaps(x, gain_delta, head, served, *, eps: float, quant):
     return (best - got) / jnp.std(logits, -1), jnp.argmax(logits, -1)
 
 
-def hidden_rows(config: dict, seed: int, tokens, rows, quant=None):
-    """Final-layer hidden states (before the final norm) at ``rows`` of
-    the sequence ``tokens``, float32 (len(rows), d)."""
-    s = Shapes.of(config)
-    eps, theta = float(config["rms_norm_eps"]), float(config["rope_theta"])
-    key = W.seed_key(seed)
-    T = len(tokens)
-    Tp = -(-T // PAD) * PAD
-    ids = np.zeros(Tp, np.int32)
-    ids[:T] = tokens
-    emb = _make_global(key, s=s, name="embed")
-    x = jnp.take(emb, jnp.asarray(ids), axis=0).astype(jnp.float32)
-    del emb
-    for layer in range(s.layers):
-        w = _make_layer(key, jnp.int32(layer), s=s)
-        x = _layer(x, w, s=s, eps=eps, theta=theta, quant=quant)
-        del w
-    return x[jnp.asarray(rows)]
-
-
-def gaps(config: dict, seed: int, sequences, quant=None):
+def served_gaps(sequences, hidden, final_norm, head, eps: float,
+                quant=None):
     """For each (prompt, served tokens) in ``sequences``: at every
     served token, the gap of the reference's logit of that token below
     its best, in units of the row's standard deviation; with ``quant``
     the control's own first choices are judged instead of the served
-    ones. Returns a float64 array of all gaps."""
-    s = Shapes.of(config)
-    eps = float(config["rms_norm_eps"])
-    key = W.seed_key(seed)
-    head = _make_global(key, s=s, name="lm_head")
-    fnorm = _make_global(key, s=s, name="final_norm")
+    ones. ``hidden(tokens, rows, quant)`` gives a reference's final
+    hidden states (float32, before the final RMSNorm, whose gain is
+    ``1 + final_norm``) at ``rows`` of ``tokens``; ``head`` (d, vocab)
+    is the output head. Returns a float64 array of all gaps."""
     out = []
     for prompt, served in sequences:
         seq = list(prompt) + list(served[:-1])
@@ -164,13 +122,14 @@ def gaps(config: dict, seed: int, sequences, quant=None):
         rows = np.pad(rows, (0, -n % ROWS), mode="edge")
         tok = jnp.asarray(np.pad(np.asarray(served, np.int32),
                                  (0, -n % ROWS)))
-        h = hidden_rows(config, seed, seq, rows)
+        h = hidden(seq, rows, None)
         if quant is None:
-            g, _ = _head_gaps(h, fnorm, head, tok, eps=eps, quant=None)
+            g, _ = _head_gaps(h, final_norm, head, tok, eps=eps, quant=None)
         else:
-            hq = hidden_rows(config, seed, seq, rows, quant=quant)
-            _, pick = _head_gaps(hq, fnorm, head, tok, eps=eps,
+            hq = hidden(seq, rows, quant)
+            _, pick = _head_gaps(hq, final_norm, head, tok, eps=eps,
                                  quant=quant)
-            g, _ = _head_gaps(h, fnorm, head, pick, eps=eps, quant=None)
+            g, _ = _head_gaps(h, final_norm, head, pick, eps=eps,
+                              quant=None)
         out.append(np.asarray(g, np.float64)[:n])
     return np.concatenate(out) if out else np.zeros(0)

@@ -61,7 +61,6 @@ class Sent:
 class Run:
     """What a run recorded; per-layer metric readers take this."""
     cell: spec.Cell
-    shapes: costs.Shapes
     peaks: dict | None
     max_slots: int
     block_size: int
@@ -255,9 +254,7 @@ def open_chip(cell, root: str, require_chip: bool = True):
 
 def make_weights(cell, seed: int):
     import jax
-
-    from bench import weights
-    params = weights.program_params(seed, costs.Shapes.of(cell.config))
+    params = cell.arch.program_params(seed, cell.config)
     jax.block_until_ready(params)
     return params
 
@@ -266,15 +263,15 @@ def serve(cell, params, seed: int, seconds: float, *, peaks=None,
           trace_dir: str | None = None, faults=None) -> Run:
     """Build the engine over ``params``, warm it up, send the cell's
     traffic and measure one window; the engine is gone on return."""
-    shapes = costs.Shapes.of(cell.config)
-    engine = prog.build_engine(cell.config, cell.traffic, params)
+    engine = prog.build_engine(cell, params)
     t = clock()
     prog.warm_up(engine)
     t_warm = clock() - t
     if faults is not None:
         faults(engine)
     probe = prog.Probe(engine)
-    planned = generate.plan(cell.traffic, seed, shapes.vocab, seconds)
+    planned = generate.plan(cell.traffic, seed,
+                            cell.arch.vocab(cell.config), seconds)
     tracer = None
     if trace_dir is not None:
         from bench import trace
@@ -282,7 +279,7 @@ def serve(cell, params, seed: int, seconds: float, *, peaks=None,
     compiles = check.CompileCounter()
     sent, window, lateness = asyncio.run(_drive(
         engine, probe, cell, planned, seconds, tracer))
-    run = Run(cell=cell, shapes=shapes, peaks=peaks,
+    run = Run(cell=cell, peaks=peaks,
               max_slots=engine.max_slots, block_size=engine.block_size,
               window=window, sent=sent, ticks=probe.ticks,
               admissions=probe.admissions)

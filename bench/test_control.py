@@ -8,7 +8,7 @@ import functools
 
 import pytest
 
-from bench import check, reference, run, spec
+from bench import check, run, spec
 from bench.conftest import TINY_CONFIG, TINY_TRAFFIC, make_root
 
 CONFIG = dict(TINY_CONFIG, hidden_size=256, num_attention_heads=8,
@@ -32,7 +32,7 @@ def test_control_fails_where_the_program_passes(cell, seed):
     r = run.serve(cell, run.make_weights(cell, seed), seed, 3.0)
     program = check.judge(cell, seed, r)
     control = check.judge(cell, seed, r, gap_fn=functools.partial(
-        reference.gaps, quant="fp8"))
+        cell.arch.gaps, quant="fp8"))
     assert program["correct"] is True
     assert control["correct"] is False
     assert control["limits"]["logit_gap"]["value"] > \

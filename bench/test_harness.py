@@ -2,18 +2,21 @@
 tiny size on the CPU, and the arithmetic of the end-to-end metrics."""
 from __future__ import annotations
 
+import hashlib
 import json
 import os
+import re
 import shutil
 import subprocess
 import sys
 import types
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench import costs, run, spec, weights
+from bench import run, spec, weights
 from bench.conftest import REPO, TINY_CONFIG, TINY_TRAFFIC, make_root
 from bench.program import Admission, Tick
 
@@ -26,6 +29,10 @@ def _last_json(text: str):
     return json.loads(lines[-1]) if lines else None
 
 
+CONTRACT = ("program_config", "program_params", "gaps", "hidden_rows",
+            "vocab", "decode_tick", "prefill_chunk", "kernel_calls")
+
+
 def test_each_cell_finds_its_files():
     for w in spec.load_benchmark(REPO)["workloads"]:
         cell = spec.load_cell(REPO, w["name"])
@@ -33,6 +40,7 @@ def test_each_cell_finds_its_files():
         assert cell.end_to_end and cell.per_layer
         for m in cell.per_layer:
             assert callable(spec.metric_reader(REPO, m["name"]))
+        assert all(callable(getattr(cell.arch, f)) for f in CONTRACT)
 
 
 def test_a_dropped_in_file_is_found_by_name(tmp_path):
@@ -78,6 +86,133 @@ def test_a_dropped_in_file_is_found_by_name(tmp_path):
         {k: v for k, v in before.items() if k in after}
 
 
+@pytest.mark.parametrize("change, error, words", [
+    (lambda c: c.pop("architecture"), ValueError, 'no "architecture" key'),
+    (lambda c: c.update(architecture="no_such_arch"), FileNotFoundError,
+     os.path.join("bench", "arch", "no_such_arch.py")),
+], ids=["no key", "no module"])
+def test_a_configuration_without_its_architecture_is_refused(
+        tmp_path, change, error, words):
+    config = json.loads(json.dumps(TINY_CONFIG))
+    change(config)
+    root = make_root(tmp_path, config)
+    with pytest.raises(error, match=re.escape(words)) as e:
+        spec.load_cell(root, "tiny.open")
+    assert "bench/configs/tiny.json" in str(e.value)
+
+
+# A second architecture as new files: the dense decoder with its output
+# head tied to the embedding, and global leaves of ids of its own.
+TIED_ARCH = '''"""The dense GQA decoder with its output head tied to the embedding:
+logits are h . embed^T, and the embedding is scaled by sqrt(hidden), as
+the program serves a tied dense model. The layers are the dense
+decoder's; the table and the final norm have leaf ids of their own."""
+import math
+
+import jax
+import jax.numpy as jnp
+
+from bench import reference as R
+from bench import weights as W
+from bench.arch import dense_gqa as D
+
+EMBED, FINAL_NORM = 100, 101
+
+program_config, vocab = D.program_config, D.vocab
+decode_tick, prefill_chunk = D.decode_tick, D.prefill_chunk
+kernel_calls = D.kernel_calls
+
+
+def _globals(key, s):
+    return (W.leaf(key, EMBED, (s.vocab, s.d), W.EMBED_LOG2),
+            W.leaf(key, FINAL_NORM, (s.d,), W.NORM_LOG2))
+
+
+_make_globals = jax.jit(_globals, static_argnums=1)
+
+
+def program_params(seed, config):
+    s = D.Shapes.of(config)
+
+    def make(key):
+        embed, norm = _globals(key, s)
+        return {"embed": embed, "final_ln": {"scale": norm},
+                "layers": D.program_layers(key, s)}
+    return jax.jit(make)(W.seed_key(seed))
+
+
+def hidden_rows(config, seed, tokens, rows, quant=None):
+    s = D.Shapes.of(config)
+    emb, _ = _make_globals(W.seed_key(seed), s)
+    x = jnp.take(emb, jnp.asarray(R.padded(tokens)), axis=0)
+    x = x.astype(jnp.float32) * math.sqrt(s.d)
+    return D.run_layers(config, seed, x, quant)[jnp.asarray(rows)]
+
+
+def gaps(config, seed, sequences, quant=None):
+    emb, norm = _make_globals(W.seed_key(seed), D.Shapes.of(config))
+    return R.served_gaps(
+        sequences, lambda seq, rows, q: hidden_rows(config, seed, seq,
+                                                    rows, q),
+        norm, emb.T, float(config["rms_norm_eps"]), quant)
+'''
+
+
+def _files(top) -> dict:
+    return {os.path.join(dp, f): open(os.path.join(dp, f), "rb").read()
+            for dp, _, fs in os.walk(top) for f in fs
+            if not f.endswith(".pyc")}
+
+
+def test_a_new_architecture_is_new_files(tmp_path, capsys):
+    """A tiny checkout gains an architecture module, a configuration
+    that names it and a cell, and runs it to ``correct``, traced and
+    not; no file of the harness changes."""
+    root = make_root(tmp_path)
+    before = {**_files(spec.BENCH_DIR), **_files(root)}
+    with open(os.path.join(root, "bench", "arch", "tied_gqa.py"),
+              "w") as f:
+        f.write(TIED_ARCH)
+    config = dict(TINY_CONFIG, name="tied", architecture="tied_gqa",
+                  tie_word_embeddings=True)
+    with open(os.path.join(root, "bench", "configs", "tied.json"),
+              "w") as f:
+        json.dump(config, f)
+    bench = spec.load_benchmark(root)
+    bench["configs"].append({"name": "tied", "source": "test",
+                             "file": "bench/configs/tied.json",
+                             "reduced": [], "why": "test"})
+    bench["workloads"].append({"name": "tied.open", "config": "tied",
+                               "traffic": "tiny", "chips": 1,
+                               "why": "test"})
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(bench, f)
+
+    cell = spec.load_cell(root, "tied.open")
+    assert cell.arch.__file__ == os.path.join(root, "bench", "arch",
+                                              "tied_gqa.py")
+    assert run.open_chip(cell, root, require_chip=False)
+    tied = cell.arch.program_params(5, cell.config)
+    dense = spec.load_cell(root, "tiny.open").arch.program_params(
+        5, TINY_CONFIG)
+    assert "lm_head" not in tied and "lm_head" in dense
+    assert not np.array_equal(tied["embed"], dense["embed"])
+    np.testing.assert_array_equal(tied["layers"]["attn"]["wq"],
+                                  dense["layers"]["attn"]["wq"])
+    args = ["--workload", "tied.open", "--seed", str(2**33 + 17),
+            "--seconds", "2"]
+    for trace in ("0", "1"):
+        assert run.main(args + ["--trace", trace], root=root,
+                        require_chip=False) == 0
+        res = _last_json(capsys.readouterr().out)
+        assert res["correct"] is True, res["limits"]
+        assert res["attempted"] > 10 and res["failed"] == 0
+    after = {**_files(spec.BENCH_DIR), **_files(root)}
+    # BENCHMARK.json gains the new entries; every other file is as it was
+    del before[os.path.join(root, "BENCHMARK.json")]
+    assert {k: after[k] for k in before} == before
+
+
 def _cmd(root, extra_env=None):
     env = dict(os.environ, JAX_PLATFORMS="cpu", **(extra_env or {}))
     env.pop("PYTHONPATH", None)
@@ -118,9 +253,10 @@ def test_weights_have_the_programs_layout_and_agree_by_layer():
     from bench import program
     program.import_program(REPO)
     from repro.models.model import build_model
-    s = costs.Shapes.of(TINY_CONFIG)
-    got = weights.program_params(9, s)
-    want = jax.eval_shape(build_model(program.model_config(TINY_CONFIG))
+    arch = spec.load_arch(REPO, TINY_CONFIG)
+    s = arch.Shapes.of(TINY_CONFIG)
+    got = arch.program_params(9, TINY_CONFIG)
+    want = jax.eval_shape(build_model(arch.program_config(TINY_CONFIG))
                           .init, jax.random.PRNGKey(0))
     assert jax.tree_util.tree_structure(got) == \
         jax.tree_util.tree_structure(want)
@@ -128,7 +264,7 @@ def test_weights_have_the_programs_layout_and_agree_by_layer():
                     jax.tree_util.tree_leaves(want), strict=True):
         assert a.shape == b.shape and a.dtype == b.dtype
     key = weights.seed_key(9)
-    one = weights.layer_weights(key, s, 1)
+    one = arch.layer_weights(key, s, 1)
     np.testing.assert_array_equal(got["layers"]["attn"]["wq"][1],
                                   one["wq"])
     np.testing.assert_array_equal(got["layers"]["mlp"]["w_down"][1],
@@ -138,6 +274,49 @@ def test_weights_have_the_programs_layout_and_agree_by_layer():
     wq = np.asarray(one["wq"], np.float32)
     assert np.abs(wq).max() <= 2.0 ** weights._log2_half(s.d)
     assert len(np.unique(wq)) > 1000
+
+
+def _digest(items) -> str:
+    h = hashlib.sha256()
+    for name, a in items:
+        a = np.asarray(a)
+        h.update(f"{name}|{a.dtype}|{a.shape}|".encode())
+        h.update(a.tobytes())
+    return h.hexdigest()
+
+
+# what the harness made and read before the dense decoder became an
+# architecture module: the program's tree, the reference's leaves (both
+# exact), and the reference's gaps over one sequence (float32 on the CPU)
+PROGRAM_DIGEST = \
+    "f4721efea778a16f2d9ca48d17bb891db862414e7ff04bee7b15b7f8a865df93"
+REFERENCE_DIGEST = \
+    "157b223ae6ab7a6ed61b85cf9454868db6158728c9ba4c6536eb966c34857234"
+GAPS = [4.085655689239502, 3.379556179046631, 2.0903677940368652,
+        1.4823611974716187, 2.393129587173462, 0.8508382439613342,
+        4.324252605438232, 2.7676799297332764, 1.676171898841858,
+        4.041431427001953, 0.6789654493331909, 1.7910741567611694,
+        1.5336527824401855, 2.633242130279541, 2.7158641815185547]
+
+
+def test_dense_weights_and_reference_are_the_ones_made_before():
+    arch = spec.load_arch(REPO, TINY_CONFIG)
+    s = arch.Shapes.of(TINY_CONFIG)
+    flat = jax.tree_util.tree_flatten_with_path(
+        arch.program_params(9, TINY_CONFIG))[0]
+    assert _digest((jax.tree_util.keystr(k), v) for k, v in flat) == \
+        PROGRAM_DIGEST
+    key = weights.seed_key(9)
+    items = [(n, arch._make_global(key, s=s, name=n))
+             for n in ("embed", "final_norm", "lm_head")]
+    for layer in range(s.layers):
+        w = arch._make_layer(key, jnp.int32(layer), s=s)
+        items += [(f"{layer}.{n}", w[n]) for n in sorted(w)]
+    assert _digest(items) == REFERENCE_DIGEST
+    toks = [(7 * i + 3) % 500 + 3 for i in range(45)]
+    np.testing.assert_allclose(
+        arch.gaps(TINY_CONFIG, 9, [(toks[:30], toks[30:])]), GAPS,
+        rtol=1e-5)
 
 
 def test_whole_run_on_the_cpu(tiny_root, capsys):
@@ -170,9 +349,8 @@ def test_traced_run_on_the_cpu_reports_no_device_number(tiny_root,
 
 def _fake_run(sent, window=(10.0, 20.0)):
     cell = types.SimpleNamespace(config=TINY_CONFIG, traffic=TINY_TRAFFIC)
-    return run.Run(cell=cell, shapes=costs.Shapes.of(TINY_CONFIG),
-                   peaks=None, max_slots=4, block_size=16, window=window,
-                   sent=sent, ticks=[], admissions=[])
+    return run.Run(cell=cell, peaks=None, max_slots=4, block_size=16,
+                   window=window, sent=sent, ticks=[], admissions=[])
 
 
 def test_unserved_request_counts_with_its_wait_so_far():

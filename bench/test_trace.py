@@ -10,13 +10,14 @@ import pytest
 
 from bench import costs, trace
 from bench.program import Admission, Tick
-from bench.spec import BENCH_DIR, load_cell
+from bench.spec import BENCH_DIR, load_arch, load_cell
 
 MS = 1_000_000
 
 
 def _run(config, ticks, admissions, block=16, peaks=None):
-    cell = types.SimpleNamespace(config=config)
+    cell = types.SimpleNamespace(
+        config=config, arch=load_arch(os.path.dirname(BENCH_DIR), config))
     return types.SimpleNamespace(cell=cell, ticks=ticks,
                                  admissions=admissions, block_size=block,
                                  peaks=peaks)
@@ -31,8 +32,8 @@ def test_union_and_owner():
 
 
 def test_summary_of_hand_made_events():
-    cfg = load_cell(os.path.dirname(BENCH_DIR), "qwen14b.decode").config
-    s = costs.Shapes.of(cfg)
+    cell = load_cell(os.path.dirname(BENCH_DIR), "qwen14b.decode")
+    cfg, arch = cell.config, cell.arch
     peaks = costs.load_peaks("TPU v5 lite")
     # two ticks, one admission of two chunks, over 100 ms
     spans = [["bench.tick", 0, 20 * MS, 0], ["bench.admit", 30 * MS,
@@ -58,16 +59,17 @@ def test_summary_of_hand_made_events():
     assert t.chunk_device_s == pytest.approx(0.030)
     assert t.chunk_count == 2
     assert t.kernel_device_s == pytest.approx(0.008)
-    work = [costs.paged_kernel(s, [100, 200], 1, 16)] * s.layers \
-        + [costs.paged_kernel(s, [101, 201], 1, 16)] * s.layers \
-        + [costs.paged_kernel(s, [64], 64, 16)] * s.layers \
-        + [costs.paged_kernel(s, [100], 36, 16)] * s.layers
+    work = arch.kernel_calls(cfg, [100, 200], 1, 16) \
+        + arch.kernel_calls(cfg, [101, 201], 1, 16) \
+        + arch.kernel_calls(cfg, [64], 64, 16) \
+        + arch.kernel_calls(cfg, [100], 36, 16)
+    assert len(work) == 4 * cfg["num_hidden_layers"]
     want = sum(costs.min_seconds(f, b, peaks)[0] for f, b in work)
     assert t.kernel_min_s == pytest.approx(want)
-    flops = costs.decode_tick(s, [100, 200])[0] \
-        + costs.decode_tick(s, [101, 201])[0] \
-        + costs.prefill_chunk(s, 0, 64, False)[0] \
-        + costs.prefill_chunk(s, 64, 36, True)[0]
+    flops = arch.decode_tick(cfg, [100, 200])[0] \
+        + arch.decode_tick(cfg, [101, 201])[0] \
+        + arch.prefill_chunk(cfg, 0, 64, False)[0] \
+        + arch.prefill_chunk(cfg, 64, 36, True)[0]
     assert t.step_flops == pytest.approx(flops)
     assert t.step_device_s == pytest.approx(0.06)
     idle = dict(t.breakdown["idle_gaps"])

@@ -154,7 +154,7 @@ def _owner(spans, t):
 
 
 def summarize(ev: dict, run) -> Summary:
-    s = costs.Shapes.of(run.cell.config)
+    arch, config = run.cell.arch, run.cell.config
     ops = [tuple(o) for o in ev["ops"]]
     mods = [tuple(m) for m in ev["modules"]]
     spans = sorted((tuple(x) for x in ev["spans"] if x[0] != CAPTURE),
@@ -196,19 +196,18 @@ def summarize(ev: dict, run) -> Summary:
     step_flops = 0.0
     for i in tick_dev:
         ctx = run.ticks[i].contexts
-        f, _ = costs.decode_tick(s, ctx)
+        f, _ = arch.decode_tick(config, ctx)
         step_flops += f
-        kern_work += [costs.paged_kernel(s, ctx, 1, bs)] * s.layers
+        kern_work += arch.kernel_calls(config, ctx, 1, bs)
     chunk_count = 0
     for j in admit_dev:
         a = run.admissions[j]
         n_prompt = sum(n for _, n in a.chunks) + (a.chunks[0][0]
                                                  if a.chunks else 0)
         for c0, n in a.chunks:
-            f, _ = costs.prefill_chunk(s, c0, n, c0 + n == n_prompt)
+            f, _ = arch.prefill_chunk(config, c0, n, c0 + n == n_prompt)
             step_flops += f
-            kern_work += [costs.paged_kernel(s, [c0 + n], n, bs)] \
-                * s.layers
+            kern_work += arch.kernel_calls(config, [c0 + n], n, bs)
             chunk_count += 1
     bound = "none"
     if peaks is not None and kern_work:
