@@ -34,8 +34,20 @@ parsed, as ``config``:
     ``(FLOPs, bytes)`` of one prompt chunk at positions ``start``
     onward; ``last`` if it holds the prompt's last token.
 ``kernel_calls(config, contexts, queries, block)``
-    ``(FLOPs, bytes)`` of each paged attention kernel call of one step
-    (one entry per call, e.g. one per attention layer) whose last
-    queries attend ``contexts`` keys, ``queries`` tokens a sequence,
-    kv blocks of ``block`` tokens.
+    ``(kernel, FLOPs, bytes)`` of each kernel call of one step (one
+    entry per call, e.g. one per attention layer) whose last queries
+    attend ``contexts`` keys, ``queries`` tokens a sequence, kv blocks
+    of ``block`` tokens. ``kernel`` is the name the program gives the
+    Pallas kernel (``pl.pallas_call(name=...)``): the trace finds the
+    kernel's ops by it (``%<kernel>.<n>``), and a metric reads its
+    share of its roofline as ``trace.roofline(run, kernel)``.
+
+And one constant:
+
+``SPANS``
+    Names of host spans the program writes for this architecture, kept
+    in a trace beside the harness's list (``trace.SPANS``) with their
+    attributes: a metric reads them from ``run.trace.spans``. A counter
+    of a new mechanism is carried as an attribute of such a span, or of
+    ``engine.tick``.
 """

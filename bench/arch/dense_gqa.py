@@ -29,6 +29,9 @@ from bench import reference as R
 from bench import weights as W
 from bench.costs import BF16, F32
 
+KERNEL = "paged_attention"        # the program's Pallas kernel's name=
+SPANS = ()                        # the harness's own list is enough
+
 # leaf name -> id: the order is part of the weights' definition
 LEAF_IDS = {name: i for i, name in enumerate((
     "embed", "final_norm", "lm_head", "attn_norm", "wq", "wk", "wv",
@@ -297,6 +300,6 @@ def paged_kernel(s: Shapes, contexts, queries: int,
 
 def kernel_calls(config: dict, contexts, queries: int,
                  block: int) -> list:
-    """One kernel call per layer, each over every key."""
+    """One ``paged_attention`` call per layer, each over every key."""
     s = Shapes.of(config)
-    return [paged_kernel(s, contexts, queries, block)] * s.layers
+    return [(KERNEL, *paged_kernel(s, contexts, queries, block))] * s.layers

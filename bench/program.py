@@ -5,8 +5,10 @@ pool with the default plan, the async front end over it) from a
 configuration file and the model configuration that the cell's
 architecture module gives, hands it the benchmark's weights, and wraps
 two methods of the engine instance, ``tick`` and ``admit``, to record
-when each ran, to name it in a profiler trace, and to stop the engine
-when the window closes. Nothing else of the program is read.
+when each ran and the engine's tick count then (which the program's
+``engine.tick`` span carries as its ``index``, so a trace finds each
+record), and to stop the engine when the window closes. Nothing else of
+the program is read.
 """
 from __future__ import annotations
 
@@ -15,8 +17,6 @@ import os
 import sys
 import threading
 import time
-
-import jax
 
 clock = time.monotonic
 
@@ -70,6 +70,7 @@ class Tick:
     start: float
     end: float
     contexts: list          # keys each active sequence attends
+    index: int              # the engine's tick count before it
 
 
 @dataclasses.dataclass
@@ -78,6 +79,7 @@ class Admission:
     start: float
     end: float
     chunks: list            # (first position, tokens) of each chunk run
+    tick: int               # the engine's tick count as it admitted
 
 
 class Probe:
@@ -86,7 +88,6 @@ class Probe:
     def __init__(self, engine):
         self.engine = engine
         self.stop = threading.Event()
-        self.annotate = False          # name the calls in a trace
         self.ticks: list[Tick] = []
         self.admissions: list[Admission] = []
         self._tick, self._admit = engine.tick, engine.admit
@@ -98,28 +99,18 @@ class Probe:
         eng = self.engine
         ctx = [int(p) + 1 for p, r in zip(eng.pos, eng.slot_req,
                                           strict=True) if r is not None]
-        t = clock()
-        if self.annotate:
-            with jax.profiler.TraceAnnotation(
-                    "bench.tick", index=len(self.ticks)):
-                self._tick()
-        else:
-            self._tick()
-        self.ticks.append(Tick(t, clock(), ctx))
+        t, index = clock(), eng.ticks
+        self._tick()
+        self.ticks.append(Tick(t, clock(), ctx, index))
 
     def admit(self, req) -> bool:
         if self.stop.is_set():
             raise WindowClosed
-        t = clock()
-        if self.annotate:
-            with jax.profiler.TraceAnnotation(
-                    "bench.admit", index=len(self.admissions)):
-                ok = self._admit(req)
-        else:
-            ok = self._admit(req)
+        t, tick = clock(), self.engine.ticks
+        ok = self._admit(req)
         if ok:
             self.admissions.append(Admission(
-                req.rid, t, clock(), self._chunks(req)))
+                req.rid, t, clock(), self._chunks(req), tick))
         return ok
 
     def _chunks(self, req) -> list:

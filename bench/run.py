@@ -114,10 +114,8 @@ async def _drive(engine, probe, cell, planned, seconds, tracer) -> tuple:
         await asyncio.sleep(max(0.0, w0 + (seconds - TRACE_SECONDS) / 2
                                 - clock()))
         await asyncio.to_thread(tracer.start)
-        probe.annotate = True
         with tracer.span():
             await asyncio.sleep(TRACE_SECONDS)
-        probe.annotate = False
         await asyncio.to_thread(tracer.stop)
 
     trace_task = asyncio.ensure_future(traced()) if tracer else None
@@ -299,10 +297,10 @@ def serve(cell, params, seed: int, seconds: float, *, peaks=None,
         run.trace = t = tracer.reduce(run)
         shutil.rmtree(trace_dir, ignore_errors=True)
         print(f"[trace] {t.window_s} s traced: {len(t.tick_device_s)} "
-              f"ticks, {t.chunk_count} prefill chunks; paged kernel "
-              f"{t.kernel_device_s} s on the device against "
-              f"{t.kernel_min_s} s of least time, {t.kernel_bound}-bound",
-              file=sys.stderr)
+              f"ticks, {t.chunk_count} prefill chunks" + "".join(
+                  f"; kernel {name} {k.device_s} s on the device against "
+                  f"{k.min_s} s of least time, {k.bound}-bound"
+                  for name, k in t.kernels.items()), file=sys.stderr)
     return run
 
 

@@ -96,33 +96,35 @@ def test_paged_kernel_bytes(name):
     # call a layer
     calls = arch.kernel_calls(cfg, [100, 200], 1, 16)
     assert len(calls) == L and len(set(calls)) == 1
-    f, b = calls[0]
+    name, f, b = calls[0]
+    assert name == "paged_attention"
     assert b == (7 + 13) * 16 * 4096 + 2 * 2 * 1 * H * 128 * 4
     assert f == 4 * H * 128 * 300
     # n = 64: one chunk whose last query attends 1000 keys
     calls = arch.kernel_calls(cfg, [1000], 64, 16)
     assert len(calls) == L and len(set(calls)) == 1
-    f, b = calls[0]
+    name, f, b = calls[0]
+    assert name == "paged_attention"
     assert b == 63 * 16 * 4096 + 2 * 64 * H * 128 * 4
     assert f == 4 * H * 128 * (64 * 1000 - 63 * 64 // 2)
 
 
 # (FLOPs, bytes) the harness counted before the dense decoder became an
-# architecture module, at the contexts above: a step of the cells reads
-# the same work
+# architecture module, at the contexts above, a kernel call's under the
+# kernel's name: a step of the cells reads the same work
 PINNED = {
     "qwen2.5-14b-8L": {
         "decode_tick": (17932779520, 5973161984),
         "prefill_first": (282198016000, 4407050240),
         "prefill_last": (45711196160, 5964578816),
-        "kernel_tick": (6144000, 1392640),
-        "kernel_chunk": (1269432320, 6750208)},
+        "kernel_tick": ("paged_attention", 6144000, 1392640),
+        "kernel_chunk": ("paged_attention", 1269432320, 6750208)},
     "internlm2-20b-6L": {
         "decode_tick": (17498456064, 5826728448),
         "prefill_first": (299880677376, 4683350016),
         "prefill_last": (48048095232, 5820495360),
-        "kernel_tick": (7372800, 1409024),
-        "kernel_chunk": (1523318784, 7274496)},
+        "kernel_tick": ("paged_attention", 7372800, 1409024),
+        "kernel_chunk": ("paged_attention", 1523318784, 7274496)},
 }
 
 

@@ -2,6 +2,7 @@
 tiny size on the CPU, and the arithmetic of the end-to-end metrics."""
 from __future__ import annotations
 
+import glob
 import hashlib
 import json
 import os
@@ -9,6 +10,7 @@ import re
 import shutil
 import subprocess
 import sys
+import time
 import types
 
 import jax
@@ -41,6 +43,7 @@ def test_each_cell_finds_its_files():
         for m in cell.per_layer:
             assert callable(spec.metric_reader(REPO, m["name"]))
         assert all(callable(getattr(cell.arch, f)) for f in CONTRACT)
+        assert isinstance(cell.arch.SPANS, tuple)
 
 
 def test_a_dropped_in_file_is_found_by_name(tmp_path):
@@ -118,7 +121,7 @@ from bench.arch import dense_gqa as D
 
 EMBED, FINAL_NORM = 100, 101
 
-program_config, vocab = D.program_config, D.vocab
+program_config, vocab, SPANS = D.program_config, D.vocab, D.SPANS
 decode_tick, prefill_chunk = D.decode_tick, D.prefill_chunk
 kernel_calls = D.kernel_calls
 
@@ -209,6 +212,129 @@ def test_a_new_architecture_is_new_files(tmp_path, capsys):
         assert res["attempted"] > 10 and res["failed"] == 0
     after = {**_files(spec.BENCH_DIR), **_files(root)}
     # BENCHMARK.json gains the new entries; every other file is as it was
+    del before[os.path.join(root, "BENCHMARK.json")]
+    assert {k: after[k] for k in before} == before
+
+
+# A second named kernel and a counter carried on a span, as new files: an
+# architecture whose step also calls a routed-expert kernel, named
+# ``expert_matmul`` in the program, and whose program writes a
+# ``moe.route`` span with the tokens it routed; and a metric for each.
+MOE_ARCH = '''"""The dense GQA decoder with a routed-expert kernel."""
+from bench.arch import dense_gqa as D
+
+program_config, program_params, vocab = D.program_config, \\
+    D.program_params, D.vocab
+gaps, hidden_rows = D.gaps, D.hidden_rows
+decode_tick, prefill_chunk = D.decode_tick, D.prefill_chunk
+SPANS = ("moe.route",)
+
+
+def kernel_calls(config, contexts, queries, block):
+    s = D.Shapes.of(config)
+    n = len(contexts) * queries
+    expert = ("expert_matmul", 6 * n * s.d * s.ff, 2 * 3 * s.d * s.ff)
+    return D.kernel_calls(config, contexts, queries, block) \\
+        + [expert] * s.layers
+'''
+MOE_METRICS = {
+    "expert_matmul_roofline": '''from bench import trace
+
+
+def read(run):
+    return trace.roofline(run, "expert_matmul")
+''',
+    "routed_tokens": '''def read(run):
+    routes = [s for s in run.trace.spans if s.name == "moe.route"] \\
+        if run.trace else []
+    if not routes:
+        return None
+    return sum(s.attrs["tokens"] for s in routes) / len(routes)
+'''}
+
+
+def test_a_new_kernel_and_span_attribute_are_new_files(tmp_path):
+    """A dropped-in architecture names a second kernel and a span; the
+    profiler's record of that span keeps its attribute through the
+    harness's reduction, and dropped-in metric files read the kernel's
+    roofline share and the attribute. No file of the harness changes."""
+    from bench import costs, trace
+    root = make_root(tmp_path / "c")
+    before = {**_files(spec.BENCH_DIR), **_files(root)}
+    with open(os.path.join(root, "bench", "arch", "moe_gqa.py"), "w") as f:
+        f.write(MOE_ARCH)
+    for name, text in MOE_METRICS.items():
+        with open(os.path.join(root, "bench", "metrics", name + ".py"),
+                  "w") as f:
+            f.write(text)
+    with open(os.path.join(root, "bench", "configs", "moe.json"), "w") as f:
+        json.dump(dict(TINY_CONFIG, name="moe", architecture="moe_gqa"), f)
+    bench = spec.load_benchmark(root)
+    bench["configs"].append({"name": "moe", "source": "test",
+                             "file": "bench/configs/moe.json",
+                             "reduced": [], "why": "test"})
+    bench["workloads"].append({"name": "moe.open", "config": "moe",
+                               "traffic": "tiny", "chips": 1,
+                               "why": "test"})
+    bench["per_layer"] += [
+        {"name": n, "unit": u, "better": "higher", "source": "device_trace",
+         "layer": "kernels", "moves": "itl_p50_ms",
+         "workloads": ["moe.open"]}
+        for n, u in (("expert_matmul_roofline", "%"),
+                     ("routed_tokens", "tokens"))]
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(bench, f)
+    cell = spec.load_cell(root, "moe.open")
+
+    # the spans as this host's profiler records them, the harness's
+    # capture around them
+    cap = trace.Capture(str(tmp_path / "trace"))
+    cap.start()
+    try:
+        with cap.span():
+            for tokens in (12, 20):
+                with jax.profiler.TraceAnnotation("moe.route",
+                                                  tokens=tokens):
+                    time.sleep(0.005)
+            with jax.profiler.TraceAnnotation("moe.unnamed", tokens=99):
+                pass
+            time.sleep(0.04)
+    finally:
+        cap.stop()
+    path, = glob.glob(str(tmp_path / "trace" / "**" / "*.xplane.pb"),
+                      recursive=True)
+    ev = trace.extract(path, trace.SPANS + cell.arch.SPANS)
+    assert [(x[0], x[3]) for x in ev["spans"] if x[0] != trace.CAPTURE] \
+        == [("moe.route", {"tokens": 12}), ("moe.route", {"tokens": 20})]
+    # a tick's device work, made by hand, in the capture's last 30 ms
+    (_, c0, cd, _), = [x for x in ev["spans"] if x[0] == trace.CAPTURE]
+    t = c0 + cd - 30 * 1_000_000
+    ms = 1_000_000
+    ev["spans"].append((trace.TICK, t, 25 * ms, {"index": 4, "active": 2}))
+    ev["modules"] = [("jit_decode_tick(1)", t + ms, 20 * ms)]
+    ev["ops"] = [("%paged_attention.3", t + ms, 4 * ms),
+                 ("%fusion.7", t + 5 * ms, 2 * ms),     # the consumer
+                 ("%expert_matmul.1", t + 7 * ms, 6 * ms),
+                 ("%fusion.8", t + 13 * ms, 8 * ms)]
+    r = types.SimpleNamespace(
+        cell=cell, ticks=[Tick(0.0, 0.1, [100, 200], 4)], admissions=[],
+        block_size=16, peaks=costs.load_peaks("TPU v5 lite"))
+    r.trace = trace.summarize(ev, r)
+    names = ("routed_tokens", "expert_matmul_roofline",
+             "paged_attn_roofline", "tick_programs")
+    assert set(names) <= {m["name"] for m in cell.per_layer}
+    read = {m: spec.metric_reader(root, m)(r) for m in names}
+    work = cell.arch.kernel_calls(cell.config, [100, 200], 1, 16)
+    least = {k: sum(costs.min_seconds(f, b, r.peaks)[0]
+                    for n, f, b in work if n == k)
+             for k in ("paged_attention", "expert_matmul")}
+    assert read["routed_tokens"] == 16.0
+    assert read["expert_matmul_roofline"] == pytest.approx(
+        100 * least["expert_matmul"] / 0.006)
+    assert read["paged_attn_roofline"] == pytest.approx(
+        100 * least["paged_attention"] / 0.004)
+    assert read["tick_programs"] == 1
+    after = {**_files(spec.BENCH_DIR), **_files(root)}
     del before[os.path.join(root, "BENCHMARK.json")]
     assert {k: after[k] for k in before} == before
 
@@ -375,16 +501,17 @@ def test_per_layer_readers_on_records():
             for i in range(4)]
     r = _fake_run(sent)
     r.admissions = [Admission(rid=i, start=10.5 + i, end=10.6 + i,
-                              chunks=[(0, 8)]) for i in range(3)]
-    r.ticks = [Tick(start=11.0, end=11.1, contexts=[5, 6]),
-               Tick(start=12.0, end=12.1, contexts=[5, 6, 7, 8]),
-               Tick(start=25.0, end=25.1, contexts=[1])]
+                              chunks=[(0, 8)], tick=i) for i in range(3)]
+    r.ticks = [Tick(start=11.0, end=11.1, contexts=[5, 6], index=0),
+               Tick(start=12.0, end=12.1, contexts=[5, 6, 7, 8], index=1),
+               Tick(start=25.0, end=25.1, contexts=[1], index=2)]
     q = spec.metric_reader(REPO, "queue_wait_p90_ms")(r)
     assert q == pytest.approx(7000.0)          # rid 3: never admitted
     assert spec.metric_reader(REPO, "batch_occupancy")(r) == \
         pytest.approx(75.0)
     for name in ("decode_step_ms", "prefill_chunk_ms",
-                 "paged_attn_roofline", "device_idle_share", "step_mfu"):
+                 "paged_attn_roofline", "device_idle_share", "step_mfu",
+                 "tick_host_ms", "admit_host_ms", "tick_programs"):
         assert spec.metric_reader(REPO, name)(r) is None
 
 
