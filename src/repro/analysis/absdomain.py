@@ -241,13 +241,16 @@ class GatherTable:
     entries are arbitrary int32 garbage (freed / never-written slots).
 
     ``used`` is the ScalarTable holding per-row live lengths; the
-    ``where`` select below is the only way to recover the live range.
+    ``where`` select below is the only way to recover the live range,
+    and only for a column inside ``width`` when that is given.
     """
 
-    def __init__(self, name: str, num_blocks: int, used: ScalarTable):
+    def __init__(self, name: str, num_blocks: int, used: ScalarTable,
+                 width: int | None = None):
         self.name = name
         self.live = Interval(0, num_blocks - 1)
         self.used = used
+        self.width = width           # columns; None: not modeled
 
     def __getitem__(self, idx):
         row, col = idx
@@ -274,10 +277,14 @@ class GatherLoad(_SymBase):
 def _is_liveness_guard(cond: Guard, load: GatherLoad) -> bool:
     """Is ``cond`` exactly ``load.col < used[load.row]`` for the used
     table the gather table itself was declared with, over a column
-    that cannot be negative?"""
+    that cannot be negative nor pass the table's width (the select
+    guards the value, not the read beside it)?"""
+    col = as_interval(load.col)
+    width = load.table.width
     return (cond.op == "lt"
             and cond.lhs is load.col
-            and as_interval(load.col).lo >= 0
+            and col.lo >= 0
+            and (width is None or col.hi < width)
             and isinstance(cond.rhs, ScalarLoad)
             and cond.rhs.table is load.table.used
             and cond.rhs.idx is load.row)

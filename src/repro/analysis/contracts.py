@@ -125,7 +125,12 @@ def check_wqk_grid(shapes: Sequence | None = None) -> list[str]:
 def check_paged_grid(workloads: Sequence[dict] | None = None
                      ) -> list[str]:
     """BlockSpec divisibility + VMEM footprint for the paged-attention
-    kernel, from the same static shape arithmetic as the wrapper."""
+    kernel at the blocks per step its own rule picks, counted by hand
+    here (the kernel's ``step_vmem`` is what that rule reads)."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.kernels.paged_attention import kernel as pk
     from repro.serving import paged
 
     out = []
@@ -144,6 +149,11 @@ def check_paged_grid(workloads: Sequence[dict] | None = None
              max_len=1024, int8=True),
         dict(B=16, H=40, Hkv=8, n=1, E=128, dv=128, NB=512, BS=32,
              max_len=8192, int8=False),
+        # qwen2.5-14b served: the decode tick and the 64-token chunk
+        dict(B=32, H=40, Hkv=8, n=1, E=128, dv=128, NB=2048, BS=16,
+             max_len=4096, int8=False),
+        dict(B=1, H=40, Hkv=8, n=64, E=128, dv=128, NB=2048, BS=16,
+             max_len=4096, int8=False),
     )
     for w in workloads:
         B, H, Hkv, n = w["B"], w["H"], w["Hkv"], w["n"]
@@ -160,30 +170,47 @@ def check_paged_grid(workloads: Sequence[dict] | None = None
             out.append(f"{tag}: logical blocks/seq nbk={nbk} exceeds "
                        f"physical pool NB={NB}; even one sequence "
                        f"cannot be resident.")
-        # block shapes vs operand shapes (leading block-id dim indexes
-        # one pool entry; trailing dims must match the pool exactly —
-        # BlockSpec tiles of extent==dim always divide)
-        kbytes = 1 if w["int8"] else 4
-        blocks = [("q", (1, H, n, E), (B, H, n, E), 4),
-                  ("k", (1, BS, Hkv, E), (NB, BS, Hkv, E), kbytes),
-                  ("v", (1, BS, Hkv, dv), (NB, BS, Hkv, dv), kbytes),
-                  ("o", (1, H, n, dv), (B, H, n, dv), 4)]
+        # K, the blocks one grid step covers, is the kernel's own pick
+        # for the pool the planner serves (bf16, or int8 with float32
+        # row scales); the rest is this check's own arithmetic: block
+        # shapes vs operand shapes (leading block-id dim indexes one
+        # pool entry; trailing dims must match the pool exactly —
+        # BlockSpec tiles of extent==dim always divide), and one grid
+        # step's VMEM, unpadded, so a lower bound of what it holds
+        pool_dt = jnp.int8 if w["int8"] else jnp.bfloat16
+        kbytes = jnp.dtype(pool_dt).itemsize
+        sds = jax.ShapeDtypeStruct
+        sc = sds((NB, BS, Hkv, 1), jnp.float32) if w["int8"] else None
+        K = pk.blocks_per_step(
+            sds((B, H, n, E), jnp.float32),
+            sds((NB, BS, Hkv, E), pool_dt), nbk,
+            v_pool=sds((NB, BS, Hkv, dv), pool_dt), k_scale=sc,
+            v_scale=sc)
+        S = K * BS                                # tokens a step covers
+        blocks = [("q", (1, H, n, E), (B, H, n, E), 4, 1),
+                  ("k", (1, BS, Hkv, E), (NB, BS, Hkv, E), kbytes, K),
+                  ("v", (1, BS, Hkv, dv), (NB, BS, Hkv, dv), kbytes, K),
+                  ("o", (1, H, n, dv), (B, H, n, dv), 4, 1)]
+        if w["int8"]:
+            blocks += [(name, (1, BS, Hkv, 1), (NB, BS, Hkv, 1), 4, K)
+                       for name in ("ks", "vs")]
         resident = 0
-        for name, blk, full, nbytes in blocks:
+        for name, blk, full, nbytes, count in blocks:
             for bdim, fdim in zip(blk, full, strict=True):
                 if fdim % bdim:
                     out.append(f"{tag}: {name} block dim {bdim} does "
                                f"not divide operand dim {fdim}.")
-            sz = nbytes
+            sz = nbytes * count
             for bdim in blk:
                 sz *= bdim
-            resident += sz
-        if w["int8"]:
-            resident += BS * Hkv * 4 * 2          # ks/vs scale blocks
-        scratch = (H * n + H * n + H * n * dv) * 4
-        if scratch + resident > VMEM_BUDGET:
-            out.append(f"{tag}: scratch {scratch} + resident blocks "
-                       f"{resident} exceed VMEM budget {VMEM_BUDGET}.")
+            resident += 2 * sz                    # double-buffered
+        # running max and sum; the step's keys and values in float32,
+        # its scores and probabilities
+        body = (2 * H * n + S * Hkv * (E + dv) + 2 * H * n * S) * 4
+        if resident + body > VMEM_BUDGET:
+            out.append(f"{tag}: a grid step of {K} blocks holds "
+                       f"{resident} bytes of blocks and {body} of "
+                       f"values, over the VMEM budget {VMEM_BUDGET}.")
     return out
 
 

@@ -8,9 +8,10 @@ planner-reachable (config, layout, quantization, mesh-extent) workload:
      inside the operand's block grid for ALL grid points; the
      ``paged_attention`` maps read its scalar-prefetch step arrays and
      block table, modeled symbolically: the step's sequence in
-     ``[0, B)``, its logical block in ``[0, nbk)``, and the table gather
-     (live entries in ``[0, num_blocks)``, the ``j >= blocks_used[b]``
-     → null-block-0 redirect recognized explicitly, everything else
+     ``[0, B)``, the logical block of each of its K sub-blocks inside
+     the table as the wrapper pads it, and the table gather (live
+     entries in ``[0, num_blocks)``, the ``j >= blocks_used[b]`` →
+     null-block-0 redirect recognized explicitly, everything else
      degrading to full int32 and failing).
   2. **write-once coverage** — output BlockSpecs tile the output exactly
      once: no overlapping/revisiting writes across separated grid steps,
@@ -163,7 +164,8 @@ def check_in_bounds(spec: KernelSpec) -> list[str]:
                         f"{iv} for dim {d} escapes the valid range "
                         f"[0, {nb[d] - 1}] — the gather can fetch "
                         f"outside the pool (is the table access guarded "
-                        f"by its own liveness predicate?).")
+                        f"by its own liveness predicate, and inside the "
+                        f"table's width?).")
             continue
         try:
             forms = _affine_forms(blk, spec.grid)
@@ -384,7 +386,7 @@ def check_paged_quant(specs: Sequence, flags: dict,
                       workload: str = "") -> list[str]:
     """int8-operand/scale pairing + positional ref-threading proof over
     the output of ``paged_attention.kernel.build_specs``. ``specs`` is
-    the ``(name, operand, block_shape, index_map)`` list in kernel
+    the ``(name, operand, block_shape, index_maps)`` list in kernel
     unpack order; ``flags`` the has_* kwargs handed to the kernel."""
     tag = f"paged_attention[{workload}]" if workload else "paged_attention"
     out = []
@@ -399,7 +401,7 @@ def check_paged_quant(specs: Sequence, flags: dict,
     for pool_name, scale_name in _SCALE_OF.items():
         if pool_name not in by_name:
             continue
-        _, op, _, imap = by_name[pool_name]
+        _, op, _, maps = by_name[pool_name]
         if str(op.dtype) != "int8":
             continue
         if scale_name not in by_name:
@@ -408,8 +410,9 @@ def check_paged_quant(specs: Sequence, flags: dict,
                 f"the kernel would accumulate raw quantized codes "
                 f"without dequantization.")
             continue
-        _, sop, sblock, simap = by_name[scale_name]
-        if simap is not imap:
+        _, sop, sblock, smaps = by_name[scale_name]
+        if len(smaps) != len(maps) or any(
+                a is not b for a, b in zip(smaps, maps)):
             out.append(
                 f"{tag}: {scale_name} uses a different index map than "
                 f"its int8 {pool_name} — scales would dequantize rows "
@@ -495,71 +498,89 @@ def bitplane_spec(N, M, D, block_n: int = 64,
         workload=f"N={N} M={M} D={D}")
 
 
-def _step_eval(index_map, num_blocks: int, B: int, nbk: int):
+def _step_eval(index_map, num_blocks: int, B: int, nbk: int, K: int = 1):
     """abstract_eval for a paged map over the flattened grid: the grid
     step and symbolic scalar-prefetch refs through the kernel's OWN
     index map, with the abstract ``where`` injected in place of
     ``jnp.where`` where the map takes one. ``seq_of_step`` is a
     ``RunTable`` and ``start`` its ``RunStart`` (``step_arrays``' order:
-    each sequence one contiguous run of at most nbk steps)."""
+    each sequence one contiguous run of at most ceil(nbk/K) steps); the
+    block table is as wide as the wrapper pads it, a whole number of
+    K-block steps."""
     import inspect
+
+    width = -(-nbk // K)
+    params = inspect.signature(index_map).parameters
 
     def ev(grid):
         (steps,) = grid
         seq = absdomain.RunTable("seq_of_step", 0, B - 1)
         # the wrapper clips blocks_used to [1, nbk]
         used = absdomain.ScalarTable("blocks_used", 1, nbk)
-        kw = ({"_where": absdomain.where} if "_where" in
-              inspect.signature(index_map).parameters else {})
+        kw = {"_where": absdomain.where} if "_where" in params else {}
         return index_map(
             absdomain.Sym("i", 0, steps - 1), seq,
-            absdomain.RunStart("start", seq, nbk, 0, steps - 1), used,
-            absdomain.GatherTable("tables", num_blocks, used),
+            absdomain.RunStart("start", seq, width, 0, steps - 1), used,
+            absdomain.GatherTable("tables", num_blocks, used, width * K),
             absdomain.ScalarTable("qpos", 0, absdomain.INT32_MAX),
             absdomain.ScalarTable("win", 0, absdomain.INT32_MAX), **kw)
     return ev
 
 
+def _stepped(index_map) -> bool:
+    """Does the map read the step arrays (a sub-block map is a partial
+    of ``block_index_map``)? W_V / b_V maps stay affine."""
+    from repro.kernels.paged_attention import kernel as k
+    fn = getattr(index_map, "func", index_map)
+    return fn in (k.block_index_map, k.q_index_map, k.out_index_map)
+
+
 def paged_spec(operands: dict, *, B: int, n: int, NB: int, BS: int,
                nbk: int, workload: str = "") -> tuple[KernelSpec, list]:
     """KernelSpec for a paged-attention workload from ShapeDtypeStruct
-    operands (same keys as ``build_specs`` kwargs), plus the quant-
-    contract violations for the same workload."""
+    operands (same keys as ``build_specs`` kwargs, ``q`` as
+    ``paged_attend`` takes it), plus the quant-contract violations for
+    the same workload. The kernel's own rule picks K, the blocks a
+    grid step covers: each pool operand is K blocks, sub-block t named
+    ``<operand>[t]``."""
     import jax
     import jax.numpy as jnp
 
     from repro.kernels.paged_attention import kernel as k
 
     q = operands["q"]
-    specs, flags = k.build_specs(
-        q, operands["k_pool"], v_pool=operands.get("v_pool"),
-        k_scale=operands.get("k_scale"), v_scale=operands.get("v_scale"),
-        wv=operands.get("wv"), bv=operands.get("bv"))
+    pool = {name: operands.get(name)
+            for name in ("v_pool", "k_scale", "v_scale", "wv", "bv")}
+    K = k.blocks_per_step(q, operands["k_pool"], nbk, **pool)
+    B_, H, n_, E = q.shape
+    G = operands["k_pool"].shape[2]
+    qg = jax.ShapeDtypeStruct((B_, G, (H // G) * n_, E), q.dtype)
+    specs, flags = k.build_specs(qg, operands["k_pool"], K=K, **pool)
     quant_violations = check_paged_quant(specs, flags, workload=workload)
 
-    H, n_, dv = q.shape[1], q.shape[2], (
-        operands["v_pool"].shape[3] if operands.get("v_pool") is not None
-        else operands["wv"].shape[2])
-    # the maps that read the step arrays; W_V / b_V stay affine
-    stepped = (k.block_index_map, k.q_index_map, k.out_index_map)
+    vp, wv = operands.get("v_pool"), operands.get("wv")
+    Hkv, dv = (vp.shape[2], vp.shape[3]) if vp is not None \
+        else (wv.shape[1], wv.shape[2])
+    R = (H // Hkv) * n_
 
     def step_eval(imap):
-        return _step_eval(imap, NB, B, nbk) if imap in stepped else None
+        return _step_eval(imap, NB, B, nbk, K) if _stepped(imap) else None
     blocks = []
-    for name, op, block, imap in specs:
-        blocks.append(Block(
-            name, tuple(op.shape), tuple(block), imap,
-            jnp.dtype(op.dtype).itemsize, abstract_eval=step_eval(imap)))
-    out_struct = jax.ShapeDtypeStruct((B, H, n_, dv), jnp.float32)
-    blocks.append(Block("out", out_struct.shape, (1, H, n_, dv),
+    for name, op, block, maps in specs:
+        for t, imap in enumerate(maps):
+            blocks.append(Block(
+                name if len(maps) == 1 else f"{name}[{t}]",
+                tuple(op.shape), tuple(block), imap,
+                jnp.dtype(op.dtype).itemsize, abstract_eval=step_eval(imap)))
+    blocks.append(Block("out", (B, Hkv, R, dv), (1, Hkv, R, dv),
                         k.out_index_map, 4, out=True,
                         abstract_eval=step_eval(k.out_index_map)))
-    # the launched grid is the batch's live blocks: at most B * nbk
+    # the launched grid is the batch's live steps: at most B*ceil(nbk/K)
     spec = KernelSpec(
         kernel="paged_attention",
-        grid=(B * nbk,),
+        grid=(B * -(-nbk // K),),
         blocks=blocks,
-        scratch_bytes=(2 * H * n_ + H * n_ * dv) * 4,
+        scratch_bytes=2 * k.vmem_bytes(k.stats_shape(Hkv, R), jnp.float32),
         workload=workload)
     return spec, quant_violations
 
@@ -610,9 +631,13 @@ def _paged_operands(cfg, plan, msz: int):
         return jax.ShapeDtypeStruct(shape, v.dtype)
 
     leaf = {k: dev(v) for k, v in leaf.items()}
-    H = cfg.num_heads
-    if shard and H % msz == 0:
+    # query heads split with the kv heads or not at all: a device runs
+    # whole GQA groups (``_stream_kv`` replicates a non-dividing pool)
+    H, Hkv = cfg.num_heads, cfg.num_kv_heads
+    head_shard = shard and Hkv % msz == 0
+    if head_shard:
         H //= msz
+        Hkv //= msz
     dh = cfg.head_dim
     f32 = jnp.float32
     ops = {}
@@ -642,9 +667,6 @@ def _paged_operands(cfg, plan, msz: int):
             if "vs" in leaf:
                 ops["v_scale"] = leaf["vs"]
         else:                                  # pure-X: V recomputed
-            Hkv = cfg.num_kv_heads
-            if shard and Hkv % msz == 0:
-                Hkv //= msz
             ops["wv"] = jax.ShapeDtypeStruct((D_dev, Hkv, dh), f32)
             ops["bv"] = jax.ShapeDtypeStruct((Hkv, dh), f32)
     return ops, fallback

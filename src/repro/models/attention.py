@@ -417,6 +417,29 @@ def _decode_attend(p: dict, x_new: jax.Array, q, view: KVCache,
     return jnp.einsum("bhne,hed->bnd", o.astype(dt), p["wo"].astype(dt))
 
 
+def stream_operands(p: dict, x_new: jax.Array, q, pool: KVCache, be):
+    """What the streamed schedule hands ``paged_attend`` for one layer:
+    ``(query, k_pool, operands, flags)``, ``operands`` the pool's other
+    arrays and W_V / b_V by keyword, ``flags`` the X score path's
+    ``augment`` / ``requant``. The one place they are built, so the
+    kernel's blocks per step (``stream_blocks_per_step``) is read off
+    the objects the kernel is handed."""
+    if not be.uses_x_cache:
+        return (q.astype(jnp.float32), pool.k,
+                dict(v_pool=pool.v, k_scale=pool.ks, v_scale=pool.vs), {})
+    qe = be.stream_q(score_weights(p), x_new)           # (B, H, n, Daug)
+    ops = dict(k_scale=None if pool.xs is None else pool.xs[:, :, None, :])
+    if pool.v is not None:
+        ops.update(v_pool=pool.v, v_scale=pool.vs)
+    else:                           # pure-X: V recomputed block-by-block
+        ops.update(wv=p["wv"].astype(jnp.float32),
+                   bv=None if "bv" not in p else
+                   p["bv"].astype(jnp.float32))
+    flags = dict(augment=qe.shape[-1] == pool.x.shape[-1] + 1,
+                 requant=be.quantized)
+    return qe, pool.x[:, :, None, :], ops, flags       # shared X stream
+
+
 def _decode_attend_streamed(p: dict, x_new: jax.Array, q, pool: KVCache,
                             tables: jax.Array, blocks_used: jax.Array,
                             qpos: jax.Array, cfg, be,
@@ -429,35 +452,58 @@ def _decode_attend_streamed(p: dict, x_new: jax.Array, q, pool: KVCache,
     ``_decode_attend`` over ``gather_block_view`` (the parity oracle).
     """
     from repro.kernels.paged_attention import paged_attend
-    dh = cfg.head_dim
-    scale = 1.0 / math.sqrt(dh)
-    dt = x_new.dtype
-    softcap = float(cfg.logit_softcap or 0.0)
-
+    common = dict(scale=1.0 / math.sqrt(cfg.head_dim),
+                  softcap=float(cfg.logit_softcap or 0.0))
+    query, k_pool, ops, flags = stream_operands(p, x_new, q, pool, be)
     if not be.uses_x_cache:
-        o = _stream_kv(q.astype(jnp.float32), pool, tables, blocks_used,
-                       qpos, window, scale=scale, softcap=softcap)
+        o = _stream_kv(query, k_pool, ops, tables, blocks_used, qpos,
+                       window, **common)
     else:
-        qe = be.stream_q(score_weights(p), x_new)       # (B, H, n, Daug)
-        aug = qe.shape[-1] == pool.x.shape[-1] + 1
-        kp = pool.x[:, :, None, :]                      # shared X stream
-        ks = None if pool.xs is None else pool.xs[:, :, None, :]
-        common = dict(k_scale=ks, scale=scale, window=window,
-                      softcap=softcap, augment=aug, requant=be.quantized)
-        if pool.v is not None:
-            o = paged_attend(qe, kp, tables, blocks_used, qpos,
-                             v_pool=pool.v, v_scale=pool.vs, **common)
-        else:                       # pure-X: V recomputed block-by-block
-            o = paged_attend(qe, kp, tables, blocks_used, qpos,
-                             wv=p["wv"].astype(jnp.float32),
-                             bv=None if "bv" not in p else
-                             p["bv"].astype(jnp.float32), **common)
+        o = paged_attend(query, k_pool, tables, blocks_used, qpos,
+                         window=window, **ops, **flags, **common)
     from repro.sharding import act
     o = act.constrain_heads(o)      # TP: one combine, at the wo einsum
+    dt = x_new.dtype
     return jnp.einsum("bhne,hed->bnd", o.astype(dt), p["wo"].astype(dt))
 
 
-def _stream_kv(q, pool: KVCache, tables, blocks_used, qpos, window,
+def _head_shards(k_pool, mesh) -> int:
+    """Devices the kv-layout stream splits its heads over: the model
+    axis where it divides the kv heads, else none (every operand
+    replicates and each device computes all heads)."""
+    if mesh is None or k_pool.shape[2] % mesh.shape["model"]:
+        return 1
+    return mesh.shape["model"]
+
+
+def stream_blocks_per_step(p: dict, pool: KVCache, cfg, be, nbk: int, *,
+                           n: int = 1, mesh=None) -> int:
+    """K, the kv blocks one grid step of the paged kernel covers, for
+    one layer's parameters ``p`` and pool ``pool`` (arrays or shape
+    structs) at ``n`` query positions a slot: the kernel's own rule on
+    the operands ``stream_operands`` builds, on one device's heads
+    where ``_stream_kv`` splits them over ``mesh``."""
+    from repro.kernels.paged_attention import kernel as pk
+    sds = jax.ShapeDtypeStruct
+    dt = jnp.dtype(cfg.dtype)
+    query, k_pool, ops = jax.eval_shape(
+        lambda p, x, q, pool: stream_operands(p, x, q, pool, be)[:3], p,
+        sds((1, n, cfg.d_model), dt),
+        sds((1, cfg.num_heads, n, cfg.head_dim), dt), pool)
+    shards = 1 if be.uses_x_cache else _head_shards(k_pool, mesh)
+
+    def local(a, axis):             # one device's share of the heads
+        if a is None or shards == 1:
+            return a
+        shape = list(a.shape)
+        shape[axis] //= shards
+        return sds(tuple(shape), a.dtype)
+    return pk.blocks_per_step(
+        local(query, 1), local(k_pool, 2), nbk,
+        **{name: local(a, 2) for name, a in ops.items()})
+
+
+def _stream_kv(q, k_pool, ops: dict, tables, blocks_used, qpos, window,
                **kw) -> jax.Array:
     """kv-layout ``paged_attend``. Under an installed mesh it runs per
     device through ``jax.shard_map``: heads on "model", tables,
@@ -473,13 +519,13 @@ def _stream_kv(q, pool: KVCache, tables, blocks_used, qpos, window,
         return paged_attend(q, k, tb, bu, qp, v_pool=v, k_scale=ks,
                             v_scale=vs, window=win, **kw)
 
-    args = (q, pool.k, pool.v, pool.ks, pool.vs, tables, blocks_used,
-            qpos, window)
+    args = (q, k_pool, ops["v_pool"], ops["k_scale"], ops["v_scale"],
+            tables, blocks_used, qpos, window)
     mesh = act.current_mesh()
     if mesh is None:
         return local(*args)
     from jax.sharding import PartitionSpec as P
-    head = "model" if pool.k.shape[2] % mesh.shape["model"] == 0 else None
+    head = "model" if _head_shards(k_pool, mesh) > 1 else None
     heads, rows = P(None, head), P(None, None, head)
     # check_vma off: the jnp twin's while-loop carries start from
     # constants, which the varying-axes check rejects against its

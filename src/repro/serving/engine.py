@@ -429,6 +429,16 @@ class Engine:
                             stacklevel=2)
             if mesh is not None and not self._shard_pool:
                 self.pool = jax.device_put(self.pool, self._rep)
+            if self.decode_schedule == "stream":
+                # K: the kv blocks one grid step of the tick's kernel
+                # covers, read off one layer's operands of it
+                from repro.models.attention import stream_blocks_per_step
+                first = jax.tree_util.tree_map(
+                    lambda a: jax.ShapeDtypeStruct(a.shape[1:], a.dtype),
+                    (self.params["layers"]["attn"], self.pool["attn"]))
+                self.kv_per_step = stream_blocks_per_step(
+                    *first, cfg, self.plan.backend, self.blocks_per_seq,
+                    mesh=mesh)
             self.tables = np.zeros((max_slots, self.blocks_per_seq),
                                    np.int32)
             self._tables_dev = None        # device copy, refreshed lazily
@@ -928,7 +938,7 @@ class Engine:
         """Per-slot live block counts covering every position up to
         ``last_pos``, on the host and clipped to [1, blocks_per_seq] —
         the streamed schedule's early-exit bound, and the paged
-        kernel's grid steps per slot. None on the gather path (the
+        kernel's live blocks per slot. None on the gather path (the
         graph then materializes the full view)."""
         if self.decode_schedule != "stream":
             return None
@@ -991,9 +1001,12 @@ class Engine:
         if not active.any():
             return
         used = self._live_blocks(self.pos) if self.paged else None
-        # kv_blocks: the grid steps the tick's paged kernel launches
-        # per layer, the sum of its clipped blocks_used
-        stats = {} if used is None else {"kv_blocks": int(used.sum())}
+        # kv_blocks: the live blocks per layer, the sum of the clipped
+        # blocks_used; kv_steps: the grid steps the tick's paged kernel
+        # launches per layer, each slot's blocks in steps of K
+        stats = {} if used is None else {
+            "kv_blocks": int(used.sum()),
+            "kv_steps": int((-(-used // self.kv_per_step)).sum())}
         with spans.span(spans.TICK, index=self.ticks,
                         active=int(active.sum()), **stats):
             self._tick(active, used)

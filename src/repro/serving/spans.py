@@ -12,10 +12,13 @@ together):
 
   pump.schedule      scheduler.step: admission and preemption
   pump.wait          blocked on the inbox, or the idle sleep
-  engine.tick        [index, active, kv_blocks] one decode tick;
-                     kv_blocks (streamed paged engines): the grid steps
-                     its paged kernel launches per layer, each slot's
-                     live kv blocks, at least one per slot
+  engine.tick        [index, active, kv_blocks, kv_steps] one decode
+                     tick; streamed paged engines: kv_blocks, the live
+                     kv blocks per layer, at least one per slot;
+                     kv_steps, the grid steps its paged kernel launches
+                     per layer, each slot's blocks in steps of K, so
+                     kv_blocks / (K * kv_steps) is the live share of
+                     the blocks it fetches
     tick.upload      last tokens, positions, live blocks (and the block
                      tables when they changed) to the device
     tick.step        dispatch of the tick program (returns before the

@@ -218,6 +218,19 @@ def test_contracts_reject_lying_budget():
     assert any("UNDER" in v for v in out), out
 
 
+def test_contracts_reject_a_paged_step_over_vmem(monkeypatch):
+    # a blocks-per-step rule that ignores VMEM: the contract's own count
+    # of the served chunk's step (40 heads x 64 positions) fits at the
+    # kernel's pick, and not at 64 blocks of 16 tokens
+    from repro.kernels.paged_attention import kernel as pk
+    chunk = dict(B=1, H=40, Hkv=8, n=64, E=128, dv=128, NB=2048, BS=16,
+                 max_len=4096, int8=False)
+    assert contracts.check_paged_grid([chunk]) == []
+    monkeypatch.setattr(pk, "blocks_per_step", lambda *a, **kw: 64)
+    out = contracts.check_paged_grid([chunk])
+    assert any("a grid step of 64 blocks" in v for v in out), out
+
+
 # -------------------------------------------------------- invariant layer
 
 def test_graph_stability_and_no_host_ops_clean():
@@ -431,8 +444,8 @@ def test_kernelcheck_gather_unguarded_escapes_bounds():
     spec, _ = _paged_fixture(kc)
     bad = dataclasses.replace(spec, blocks=[
         dataclasses.replace(b, abstract_eval=kc._step_eval(
-            unguarded, 64, 4, 4)) if b.name == "k_pool" else b
-        for b in spec.blocks])
+            unguarded, 64, 4, 16, 8)) if b.name.startswith("k_pool")
+        else b for b in spec.blocks])
     out = kc.check_in_bounds(bad)
     assert any("gather" in v and "escapes" in v for v in out), out
 
@@ -453,21 +466,42 @@ def test_kernelcheck_gather_off_its_own_run_escapes_bounds():
     spec, _ = _paged_fixture(kc)
     bad = dataclasses.replace(spec, blocks=[
         dataclasses.replace(b, abstract_eval=kc._step_eval(
-            off_run, 64, 4, 4)) if b.name == "k_pool" else b
-        for b in spec.blocks])
+            off_run, 64, 4, 16, 8)) if b.name.startswith("k_pool")
+        else b for b in spec.blocks])
     out = kc.check_in_bounds(bad)
     assert any("gather" in v and "escapes" in v for v in out), out
 
 
-def test_kernelcheck_paged_grid_is_the_flattened_steps():
-    """The paged kernel's grid is one axis of at most B * nbk steps,
-    and the maps that read the step arrays are evaluated abstractly."""
+def test_kernelcheck_sub_block_past_the_padded_table_escapes_bounds():
+    """A sub-block one past the step's K reads a table column beyond
+    the padded width: the liveness select no longer proves the gather,
+    whatever it selects."""
+    import dataclasses
+    import functools
     kc = _kc()
     from repro.kernels.paged_attention import kernel as k
     spec, _ = _paged_fixture(kc)
-    assert spec.grid == (4 * 4,)
+    past = functools.partial(k.block_index_map, t=8, K=8)
+    bad = dataclasses.replace(spec, blocks=[
+        dataclasses.replace(b, abstract_eval=kc._step_eval(
+            past, 64, 4, 16, 8)) if b.name == "v_pool[7]" else b
+        for b in spec.blocks])
+    out = kc.check_in_bounds(bad)
+    assert any("v_pool[7]" in v and "escapes" in v for v in out), out
+
+
+def test_kernelcheck_paged_grid_is_the_flattened_steps():
+    """The paged kernel's grid is one axis of at most B * ceil(nbk/K)
+    steps of K = 128 // BS blocks, each pool operand K sub-blocks, and
+    the maps that read the step arrays are evaluated abstractly."""
+    kc = _kc()
+    from repro.kernels.paged_attention import kernel as k
+    spec, _ = _paged_fixture(kc)
+    assert spec.grid == (4 * 2,)                  # nbk 16, K 8
     stepped = {b.name for b in spec.blocks if b.abstract_eval is not None}
-    assert stepped == {"q", "k_pool", "v_pool", "out"}
+    assert stepped == {"q", "out"} | {f"{p}[{t}]" for p in ("k_pool",
+                                                            "v_pool")
+                                      for t in range(8)}
     out_blk = next(b for b in spec.blocks if b.out)
     assert out_blk.index_map is k.out_index_map
 
@@ -481,7 +515,7 @@ def test_kernelcheck_paged_out_map_off_the_sequence_is_flagged():
     spec, _ = _paged_fixture(kc)
     bad = dataclasses.replace(spec, blocks=[
         dataclasses.replace(b, abstract_eval=kc._step_eval(
-            k.block_index_map, 64, 4, 4)) if b.out else b
+            k.block_index_map, 64, 4, 16, 8)) if b.out else b
         for b in spec.blocks])
     out = kc.check_write_once(bad)
     assert any("write-once coverage not provable" in v for v in out), out
@@ -502,7 +536,7 @@ def _paged_fixture(kc, int8=False):
                                               jnp.float32)
         ops["v_scale"] = jax.ShapeDtypeStruct((NB, BS, Hkv, 1),
                                               jnp.float32)
-    return kc.paged_spec(ops, B=4, n=n, NB=NB, BS=BS, nbk=4,
+    return kc.paged_spec(ops, B=4, n=n, NB=NB, BS=BS, nbk=16,
                          workload="test")
 
 
@@ -546,8 +580,8 @@ def test_kernelcheck_scale_with_wrong_index_map():
     specs, flags = k.build_specs(q, kp, v_pool=vp, k_scale=ks)
     # re-point the scale at the (stationary) q map: rows would
     # dequantize against a different physical block
-    specs = [(n_, op, blk, k.q_index_map) if n_ == "k_scale"
-             else (n_, op, blk, imap) for n_, op, blk, imap in specs]
+    specs = [(n_, op, blk, (k.q_index_map,)) if n_ == "k_scale"
+             else (n_, op, blk, maps) for n_, op, blk, maps in specs]
     out = kc.check_paged_quant(specs, flags)
     assert any("DIFFERENT physical block" in v for v in out), out
 

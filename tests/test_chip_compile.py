@@ -11,8 +11,9 @@ dh=128, 16-token blocks, bf16) for a decode tick (n=1) and a prefill
 chunk (n=64), on one chip and head-sharded 4 ways through the
 ``shard_map`` the engine uses under a mesh, at a 1024-token table and
 at the served one (4096 tokens, 32 slots: a flattened grid of up to
-8,192 steps, whose step arrays Mosaic keeps in SMEM); and ``wqk_score`` at
-whisper-tiny's encoder shape (N=1500, D_aug=385, H=6).
+1,024 steps of 8 blocks, whose step arrays Mosaic keeps in SMEM); the
+shared X stream at D=512 (int8 with W_V, and bf16 with a V pool); and
+``wqk_score`` at whisper-tiny's encoder shape (N=1500, D_aug=385, H=6).
 
 The topology is described inside a module fixture — never at import —
 so every pytest-xdist worker collects the same tests and only the worker
@@ -86,7 +87,8 @@ def _paged_operands(n, sharding, head_sharding=None, nbk=NBK, slots=8):
 
 def _stream(q, pool, tables, used, qpos):
     from repro.models import attention as attn
-    return attn._stream_kv(q, pool, tables, used, qpos, None,
+    ops = dict(v_pool=pool.v, k_scale=pool.ks, v_scale=pool.vs)
+    return attn._stream_kv(q, pool.k, ops, tables, used, qpos, None,
                            scale=1 / math.sqrt(DH), softcap=0.0,
                            impl="pallas", interpret=False)
 
@@ -139,12 +141,43 @@ def test_paged_kv_kernel_shard_maps_over_four_chips(tp_mesh, n):
 def test_paged_kv_kernel_compiles_at_the_served_width(one_chip, tp_mesh,
                                                       chips, n):
     """The served table (256 blocks) and batch (32 slots): the step
-    arrays of the flattened grid hold 8,192 entries per tick."""
+    arrays of the flattened grid hold 1,024 entries per tick at 8
+    blocks a step."""
     width = dict(nbk=SERVED_NBK, slots=SERVED_SLOTS)
     if chips == 1:
         _compile_one_chip(one_chip, n, **width)
     else:
         _compile_four_chips(tp_mesh, n, **width)
+
+
+@pytest.mark.parametrize("layout", ["x_int8_wv", "xv_bf16"])
+def test_paged_x_kernel_compiles_on_one_chip(one_chip, layout):
+    """The shared X stream (one score group, the [X 1] augmentation and
+    the W8A8 requantization) at D = 512: its padded rows take the most
+    VMEM a step, so the kernel's budget rule gives it fewer blocks a
+    step than the kv pool."""
+    from repro.kernels.paged_attention import kernel as pk
+    B, D, Hq, Hv, nbk = 8, 512, 8, 2, 256
+
+    def sds(shape, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    if layout == "x_int8_wv":
+        k = sds((NB, BS, 1, D), jnp.int8)
+        extra = dict(k_scale=sds((NB, BS, 1, 1)), wv=sds((D, Hv, DH)),
+                     bv=sds((Hv, DH)))
+    else:
+        k = sds((NB, BS, 1, D), jnp.bfloat16)
+        extra = dict(v_pool=sds((NB, BS, Hv, DH), jnp.bfloat16))
+    q = sds((B, Hq, 1, D + 1))
+    assert pk.blocks_per_step(q, k, nbk, **extra) < 128 // BS
+
+    def stream(q, k, tables, used, qpos, extra):
+        return pk.paged_attend_pallas(q, k, tables, used, qpos, scale=0.1,
+                                      augment=True, requant=True, **extra)
+    compiled = jax.jit(stream).lower(
+        q, k, sds((B, nbk), jnp.int32), sds((B,), jnp.int32),
+        sds((B, 1), jnp.int32), extra).compile()
+    assert "tpu_custom_call" in compiled.as_text()
 
 
 @pytest.mark.parametrize("shape", [(1, 1500, 385, 6), (4, 448, 385, 6)],

@@ -4,9 +4,12 @@ the jnp while-loop reference AND the Pallas kernel (interpret mode on
 CPU), ragged per-sequence lengths, windowed masks, chunk-shaped (n>1)
 queries, and the ``blocks_used`` early exit (proved by NaN-poisoning
 the blocks past the live region: the stream must never touch them).
-The Pallas kernel's grid is the batch's live blocks, flattened: its
-step arrays, and tables that forks share, empty slots, a row at the
-full table width and a windowed chunk, each against the oracle."""
+The Pallas kernel's grid is the batch's live blocks in steps of K,
+flattened: its step arrays, and tables that forks share, empty slots,
+one-block rows, a row at the full table width and a windowed chunk,
+each against the oracle where one step covers the whole table
+(nbk < 128 // BS), where rows end inside a step of K < nbk blocks, and
+where the VMEM rule halves K."""
 import dataclasses
 
 import jax
@@ -24,6 +27,13 @@ IMPLS = ("jnp", "pallas")
 B, H, Hkv, dh, D = 3, 4, 2, 8, 12
 BS, NBK, NB = 4, 6, 24
 POS = np.array([5, 11, 21])           # ragged: 2 / 3 / 6 used blocks
+# (block size, K, VMEM budget) of the Pallas kernel's grid over
+# NBK-block tables: one step covers the whole table (K = NBK < 128 //
+# BS), or steps of K = 128 // BS = 4 blocks, which the 2-, 3- and
+# 6-block rows end inside, or, under a 3-MiB budget, steps the VMEM
+# rule halves from K = NBK to 3 (the 64-token chunk's from 16 to 2)
+GEOMETRY = {"one_step": (BS, NBK, None), "k4": (32, 4, None),
+            "vmem": (8, 3, 3 << 20)}
 
 
 def _rng():
@@ -36,8 +46,21 @@ def _tables(rng):
     return jnp.asarray(ids, jnp.int32)
 
 
-def _used(pos, n):
-    return jnp.asarray(-(-(pos + n) // BS), jnp.int32)
+def _used(pos, n, bs=BS):
+    return jnp.asarray(-(-(pos + n) // bs), jnp.int32)
+
+
+def _geometry(name, monkeypatch):
+    """(block size, positions of POS's blocks at that size), after
+    setting the geometry's VMEM budget and checking the kernel takes
+    the K the geometry names."""
+    bs, K, budget = GEOMETRY[name]
+    if budget is not None:
+        monkeypatch.setattr(pkernel, "VMEM_BYTES", budget)
+    q = jax.ShapeDtypeStruct((B, H, 1, dh), jnp.float32)
+    kp = jax.ShapeDtypeStruct((NB, bs, Hkv, dh), jnp.float32)
+    assert pkernel.blocks_per_step(q, kp, NBK, v_pool=kp) == K
+    return bs, POS * bs // BS
 
 
 def _dense_oracle(q, kv, vv, qpos, scale, window=None):
@@ -95,12 +118,12 @@ def test_x_layout_matches_gather_oracle(impl, int8, with_vpool):
         _tables(rng), jnp.asarray(POS[:, None]), _used(POS, 1)))
 
 
-def _check_x_layout(impl, int8, with_vpool, rows):
+def _check_x_layout(impl, int8, with_vpool, rows, bs=BS):
     """The X-layout stream against the gather oracle, over the
     ``(tables, qpos, blocks_used)`` that ``rows(rng)`` draws."""
     rng = _rng()
     n = 1
-    xf = rng.normal(size=(NB, BS, D)).astype(np.float32)
+    xf = rng.normal(size=(NB, bs, D)).astype(np.float32)
     if int8:
         xq, xs = quant.quantize(jnp.asarray(xf), axis=-1)
         xdeq = xq.astype(jnp.float32) * xs
@@ -113,14 +136,14 @@ def _check_x_layout(impl, int8, with_vpool, rows):
     wv = jnp.asarray(rng.normal(size=(D, Hkv, dh)), jnp.float32)
     bv = jnp.asarray(rng.normal(size=(Hkv, dh)), jnp.float32)
 
-    xv = jnp.take(xdeq, tables, axis=0).reshape(B, NBK * BS, D)
+    xv = jnp.take(xdeq, tables, axis=0).reshape(B, NBK * bs, D)
     xaug = jnp.concatenate([xv, jnp.ones_like(xv[..., :1])], -1)
     # requant per row == the wqk_int8 score path on the gathered view
     qy, sy = quant.quantize(xaug, axis=-1)
     kvo = (qy.astype(jnp.float32) * sy)[:, :, None, :]
     if with_vpool:
-        vf = jnp.asarray(rng.normal(size=(NB, BS, Hkv, dh)), jnp.float32)
-        vv = jnp.take(vf, tables, axis=0).reshape(B, NBK * BS, Hkv, dh)
+        vf = jnp.asarray(rng.normal(size=(NB, bs, Hkv, dh)), jnp.float32)
+        vv = jnp.take(vf, tables, axis=0).reshape(B, NBK * bs, Hkv, dh)
         vkw = dict(v_pool=vf)
     else:
         vv = jnp.einsum("bsd,dhe->bshe", xv, wv) + bv
@@ -133,17 +156,19 @@ def _check_x_layout(impl, int8, with_vpool, rows):
                                rtol=1e-4, atol=1e-5)
 
 
+@pytest.mark.parametrize("geometry", sorted(GEOMETRY))
 @pytest.mark.parametrize("impl", IMPLS)
-def test_window_mask_matches_oracle(impl):
+def test_window_mask_matches_oracle(impl, geometry, monkeypatch):
     rng = _rng()
+    bs, pos = _geometry(geometry, monkeypatch)
     q = jnp.asarray(rng.normal(size=(B, H, 1, dh)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(NB, BS, Hkv, dh)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(NB, BS, Hkv, dh)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(NB, bs, Hkv, dh)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(NB, bs, Hkv, dh)), jnp.float32)
     tables = _tables(rng)
-    qpos = jnp.asarray(POS[:, None])
-    used = _used(POS, 1)
-    kv = jnp.take(kp, tables, axis=0).reshape(B, NBK * BS, Hkv, dh)
-    vv = jnp.take(vp, tables, axis=0).reshape(B, NBK * BS, Hkv, dh)
+    qpos = jnp.asarray(pos[:, None])
+    used = _used(pos, 1, bs)
+    kv = jnp.take(kp, tables, axis=0).reshape(B, NBK * bs, Hkv, dh)
+    vv = jnp.take(vp, tables, axis=0).reshape(B, NBK * bs, Hkv, dh)
     for window in (5, jnp.asarray(7)):        # python int and traced
         want = _dense_oracle(q, kv, vv, qpos, 0.25, window=window)
         got = pops.paged_attend(q, kp, tables, used, qpos, v_pool=vp,
@@ -184,30 +209,34 @@ def test_blocks_used_early_exit_skips_dead_blocks(impl):
                                rtol=1e-5, atol=1e-5)
 
 
-def _flat_case(case, rng):
+def _flat_case(case, rng, bs, pos):
     """(tables, blocks_used, qpos, n, nbk, window) for one shape of the
-    flattened grid. ``forked``: two rows share the first physical blocks
-    of a third, as radix prefix forks make them; ``empty``: slots with no live block (the
-    wrapper clips 0 to 1, an empty slot's position is 0); ``full``: one
-    row uses the whole table; ``chunk``: one 64-token chunk (B = 1)
-    under a window."""
+    flattened grid at ``bs``-token blocks, rows ending at ``pos``.
+    ``forked``: two rows share the first physical blocks of a third, as
+    radix prefix forks make them; ``empty``: slots with no live block
+    (the wrapper clips 0 to 1, an empty slot's position is 0);
+    ``one_block``: rows of one live block each; ``full``: one row uses
+    the whole table; ``chunk``: one 64-token chunk (B = 1) under a
+    window, 21 of 22 blocks live."""
     if case == "chunk":
         nbk, n = 22, 64
         tables = rng.permutation(np.arange(1, NB))[:nbk].reshape(1, nbk)
-        last = np.array([80])
+        last = np.array([20 * bs + bs // 2])
         window = 37
     else:
         nbk, n, window = NBK, 1, None
         tables = np.array(_tables(rng))
-        last = POS.copy()
+        last = pos.copy()
         if case == "forked":
             tables[1, :3] = tables[0, :3]
             tables[2, :2] = tables[0, :2]
         if case == "full":
-            last[1] = NBK * BS - 1
+            last[1] = NBK * bs - 1
         if case == "empty":
             last[[0, 2]] = 0
-    used = last // BS + 1
+        if case == "one_block":
+            last = np.array([0, bs // 2, bs - 1])
+    used = last // bs + 1
     if case == "empty":
         used[[0, 2]] = 0
     qpos = last[:, None] - n + 1 + np.arange(n)[None, :]
@@ -217,19 +246,23 @@ def _flat_case(case, rng):
 
 def _oracle_view(pool, tables, nbk):
     return jnp.take(pool, tables, axis=0).reshape(
-        (tables.shape[0], nbk * BS) + pool.shape[2:])
+        (tables.shape[0], nbk * pool.shape[1]) + pool.shape[2:])
 
 
-@pytest.mark.parametrize("case", ["forked", "empty", "full", "chunk"])
-def test_flattened_grid_matches_gather_oracle(case):
+@pytest.mark.parametrize("geometry", sorted(GEOMETRY))
+@pytest.mark.parametrize("case", ["forked", "empty", "one_block", "full",
+                                  "chunk"])
+def test_flattened_grid_matches_gather_oracle(case, geometry,
+                                               monkeypatch):
     rng = _rng()
-    tables, used, qpos, n, nbk, window = _flat_case(case, rng)
+    bs, pos = _geometry(geometry, monkeypatch)
+    tables, used, qpos, n, nbk, window = _flat_case(case, rng, bs, pos)
     b = tables.shape[0]
     q = jnp.asarray(rng.normal(size=(b, H, n, dh)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(NB, BS, Hkv, dh)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(NB, BS, Hkv, dh)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(NB, bs, Hkv, dh)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(NB, bs, Hkv, dh)), jnp.float32)
     kv, vv = _oracle_view(kp, tables, nbk), _oracle_view(vp, tables, nbk)
-    S = nbk * BS
+    S = nbk * bs
     s = jnp.einsum("bgrne,bsge->bgrns", q.reshape(b, Hkv, -1, n, dh),
                    kv).reshape(b, H, n, S) * 0.25
     idx = jnp.arange(S)[None, None, :]
@@ -245,41 +278,82 @@ def test_flattened_grid_matches_gather_oracle(case):
                                rtol=1e-5, atol=1e-5)
 
 
-def test_flattened_grid_int8_x_pool_with_forks():
+@pytest.mark.parametrize("geometry", sorted(GEOMETRY))
+def test_flattened_grid_int8_x_pool_with_forks(geometry, monkeypatch):
     """The int8 X pool (shared stream, per-row scales, V recomputed
     through W_V) over forked rows, an empty slot and a full row."""
+    bs, _ = _geometry(geometry, monkeypatch)
+
     def rows(rng):
         tables = np.array(_tables(rng))
         tables[2, :2] = tables[1, :2]
-        return (jnp.asarray(tables), jnp.asarray([[0], [NBK * BS - 1], [6]]),
+        return (jnp.asarray(tables),
+                jnp.asarray([[0], [NBK * bs - 1], [bs + 2]]),
                 jnp.asarray([0, NBK, 2], jnp.int32))
-    _check_x_layout("pallas", True, False, rows)
+    _check_x_layout("pallas", True, False, rows, bs)
+
+
+def test_stream_blocks_per_step_reads_one_devices_operands():
+    """The engine's K is the kernel's pick on the operands each device
+    is handed: at qwen2.5-14b's widths (bf16 pool of 16-token blocks,
+    256-block tables) the 64-token chunk takes 2 blocks a step on one
+    chip and 8 on each device of a 4-way model axis, which streams 2
+    of the 8 kv heads; the decode tick takes 8 either way."""
+    import types
+
+    from repro.models import attention
+    cfg = get_arch("qwen2.5-14b")
+    be = sb.plan(cfg).backend
+    sds = jax.ShapeDtypeStruct
+    rows = sds((2048, 16, cfg.num_kv_heads, cfg.head_dim), jnp.bfloat16)
+    pool = attention.KVCache(k=rows, v=rows)
+    mesh = types.SimpleNamespace(shape={"model": 4})
+
+    def K(n, mesh=None):
+        return attention.stream_blocks_per_step({}, pool, cfg, be, 256,
+                                                n=n, mesh=mesh)
+    local = sds((2048, 16, 2, cfg.head_dim), jnp.bfloat16)
+    assert K(64) == 2
+    assert K(64, mesh) == 8 == pkernel.blocks_per_step(
+        sds((1, 10, 64, cfg.head_dim), jnp.float32), local, 256,
+        v_pool=local)
+    assert K(1) == K(1, mesh) == 8
 
 
 def test_step_arrays_walk_the_live_blocks_in_sequence_order():
-    """As many steps as clipped live blocks (more than the pool holds,
-    for forked rows), sequence-major, the logical block restarting at 0
-    for each slot, and each step's block map on that block's entry."""
-    nbk, nb = 6, 8
+    """ceil(used / K) steps per row (more than the pool holds, for
+    forked rows), sequence-major, each row's steps restarting at its
+    first block, and sub-block t of each step on its row's block j·K + t
+    while live, on the null block past the row's used blocks and past
+    the table's width (the wrapper pads the table to whole steps)."""
+    nbk, nb, K = 6, 8, 4
     tables = np.arange(24).reshape(4, nbk) % (nb - 1) + 1
     tables[3] = tables[1]                          # a fork of row 1
-    used = np.array([0, 3, 6, 6])
+    used = np.array([0, 3, 6, 5])
     clipped = np.clip(used, 1, nbk)
     seq, start, total = (np.asarray(a) for a in pkernel.step_arrays(
-        jnp.asarray(clipped, jnp.int32), nbk))
-    assert seq.shape == (4 * nbk,)
-    assert int(total) == clipped.sum() == 16 and total > nb
-    want_seq = np.repeat(np.arange(4), clipped)
-    want_j = np.concatenate([np.arange(u) for u in clipped])
+        jnp.asarray(clipped, jnp.int32), nbk, K))
+    steps = -(-clipped // K)                       # 1, 1, 2, 2
+    assert seq.shape == (4 * 2,)
+    assert int(total) == steps.sum() == 6
+    want_seq = np.repeat(np.arange(4), steps)
+    want_j = np.concatenate([np.arange(u) for u in steps])
     np.testing.assert_array_equal(seq[:total], want_seq)
-    np.testing.assert_array_equal(start, np.cumsum(clipped) - clipped)
+    np.testing.assert_array_equal(start, np.cumsum(steps) - steps)
     np.testing.assert_array_equal(np.arange(total) - start[seq[:total]],
                                   want_j)
     assert (seq[total:] == 3).all()
-    blocks = [pkernel.block_index_map(i, seq, start, clipped, tables,
-                                      _where=np.where)[0]
-              for i in range(total)]
-    np.testing.assert_array_equal(blocks, tables[want_seq, want_j])
+    padded = np.pad(tables, ((0, 0), (0, (-nbk) % K)))
+    maps = pkernel.sub_block_maps(K)
+    got = np.array([[imap(i, seq, start, clipped, padded,
+                          _where=np.where)[0] for imap in maps]
+                    for i in range(total)])
+    blk = want_j[:, None] * K + np.arange(K)[None, :]
+    live = blk < clipped[want_seq][:, None]
+    want = np.where(live, padded[want_seq[:, None], blk], 0)
+    np.testing.assert_array_equal(got, want)
+    # every live block is fetched once, by its own row's step
+    assert live.sum() == clipped.sum() > nb
 
 
 def test_stream_q_matches_backend_scores():

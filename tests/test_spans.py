@@ -25,7 +25,7 @@ PARENT = {
     spans.ADMIT: spans.PUMP_SCHEDULE, spans.ADMIT_RESERVE: spans.ADMIT,
     spans.ADMIT_CHUNK: spans.ADMIT, spans.ADMIT_SAMPLE: spans.ADMIT,
 }
-STATS = {spans.TICK: {"index", "active", "kv_blocks"},
+STATS = {spans.TICK: {"index", "active", "kv_blocks", "kv_steps"},
          spans.ADMIT: {"rid"},
          spans.ADMIT_RESERVE: {"rid"}, spans.ADMIT_CHUNK: {"rid", "c0"},
          spans.ADMIT_SAMPLE: {"rid"}}
@@ -33,7 +33,7 @@ STATS = {spans.TICK: {"index", "active", "kv_blocks"},
 
 @pytest.fixture(scope="module")
 def traced(tmp_path_factory):
-    """(spans by name, requests, kv blocks launched per traced tick):
+    """(spans by name, requests, each traced tick's blocks_used):
     spans as (start, end, stats, line)."""
     cfg = dataclasses.replace(
         reduced(get_arch("qwen2.5-14b"), num_layers=2), dtype="float32")
@@ -47,7 +47,7 @@ def traced(tmp_path_factory):
 
     def spy(params, pool, tables, tokens, pos, used, tick):
         if tick:                         # the blocks_used the tick runs
-            launched.append(int(np.asarray(used).sum()))
+            launched.append(np.asarray(used))
         return step(params, pool, tables, tokens, pos, used, tick)
     eng._decode_paged = spy
 
@@ -121,11 +121,24 @@ def test_tick_spans_count_the_ticks(traced):
 
 def test_tick_span_counts_the_launched_kv_blocks(traced):
     """``kv_blocks`` on each tick is the sum of the clipped
-    ``blocks_used`` that tick hands the paged kernel: its grid steps."""
+    ``blocks_used`` that tick hands the paged kernel: its live
+    blocks."""
     found, _, launched = traced
     kv = [s["kv_blocks"] for *_, s, _ in found[spans.TICK]]
-    assert kv == launched and len(kv) >= 4
+    assert kv == [int(u.sum()) for u in launched] and len(kv) >= 4
     assert all(2 <= k <= 2 * 8 for k in kv)    # 2 slots, 8-block rows
+
+
+def test_tick_span_counts_the_kernel_grid_steps(traced):
+    """``kv_steps`` on each tick is the paged kernel's grid: each
+    slot's live blocks in steps of K. The 8-block rows of 8-token
+    blocks take K = min(8, 128 // 8) = 8, so a step a slot."""
+    from repro.kernels.paged_attention import kernel as pkernel
+    found, _, launched = traced
+    K = min(8, pkernel.LANES // 8)
+    steps = [s["kv_steps"] for *_, s, _ in found[spans.TICK]]
+    assert steps == [int((-(-u // K)).sum()) for u in launched]
+    assert all(s == len(u) for s, u in zip(steps, launched))
 
 
 def test_tick_and_chunk_compile_once_each_under_their_names():
