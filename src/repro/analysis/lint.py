@@ -61,7 +61,7 @@ A bare ``# ra: allow[RA103]`` without a reason is itself a finding
 CLI::
 
     python -m repro.analysis.lint [paths...]   # default: src tests
-                                               # benchmarks examples
+                                               # examples
 """
 from __future__ import annotations
 
@@ -72,7 +72,7 @@ import re
 import sys
 from pathlib import Path
 
-DEFAULT_PATHS = ("src", "tests", "benchmarks", "examples")
+DEFAULT_PATHS = ("src", "tests", "examples")
 
 _ALLOW_RE = re.compile(r"#\s*ra:\s*allow\[(RA\d{3})\]\s*(.*)")
 

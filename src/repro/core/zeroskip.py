@@ -7,7 +7,7 @@ A systolic MXU cannot skip data-dependently, so on TPU this lives as:
   (a) a faithful *cycle/energy model*: given real input tensors, count the
       exact number of fired vs skipped word-line events the macro would see
       (reproduces the paper's ">=55% reduction" claim in
-      benchmarks/zeroskip_bench.py), and
+      tests/test_zeroskip_energy.py), and
   (b) the TPU-friendly analogue — token-level padding skip via sequence
       packing (data/pipeline.py) — which removes whole all-zero rows, the
       dominant source of zero bits the paper cites.

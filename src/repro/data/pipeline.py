@@ -9,7 +9,7 @@ Design constraints (DESIGN.md §5):
   * **Padding-aware** — emits ``loss_mask`` and per-sequence lengths; the
     zero-padding structure is exactly the input sparsity the paper's
     zero-skip mechanism exploits (§III.C), so the pipeline also reports
-    pad fractions for the zeroskip benchmarks.
+    pad fractions (``pad_fraction``).
   * **Packing** — optional sequence packing removes pad waste; this is
     the TPU-friendly analogue of the macro's token-level zero skipping
     (documented in core/zeroskip.py).

@@ -12,8 +12,7 @@ Usage:
     PYTHONPATH=src python -m repro.launch.dryrun --mesh multi
     PYTHONPATH=src python -m repro.launch.dryrun --skip-existing
 
-Per-cell JSON artifacts land in results/dryrun/ and are consumed by
-benchmarks/roofline.py and EXPERIMENTS.md.
+Per-cell JSON artifacts land in results/dryrun/.
 """
 import os
 

@@ -10,7 +10,7 @@ engine's ``on_token``/``on_finish`` hooks and the scheduler's step
 report — the jitted serving path is untouched.
 
 ``snapshot()`` exports one JSON-able dict (``launch/serve.py`` prints
-it; ``benchmarks/serving_async.py`` gates on it).
+it).
 """
 from __future__ import annotations
 
@@ -58,8 +58,7 @@ class RequestMetrics:
 
 def _percentile(values: list[float], q: float) -> float | None:
     """Nearest-rank percentile (q in [0, 100]); None on empty input.
-    Stdlib-only so ``check_regression``-adjacent tooling can import
-    this module without jax/numpy."""
+    Stdlib-only, so this module imports without jax/numpy."""
     if not values:
         return None
     v = sorted(values)

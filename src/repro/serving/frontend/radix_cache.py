@@ -196,8 +196,7 @@ class RadixCache:
         return self.evict(self._nodes)
 
     def reset_stats(self):
-        """Zero the counters (benchmarks: drop warm-up traffic from the
-        measured hit rate). Tree contents are untouched."""
+        """Zero the counters. Tree contents are untouched."""
         self.lookups = self.lookup_blocks = 0
         self.hits = self.hit_blocks = 0
         self.inserted_blocks = self.evicted_blocks = 0

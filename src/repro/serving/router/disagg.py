@@ -15,7 +15,7 @@ full chunk loop. Disaggregation splits the replica into:
     blocks in exactly the layout decode consumes, so the handoff is
     ``paged.export_blocks`` (bit-copy of the written blocks) plus a
     table splice, and the continuation is bit-identical to the fused
-    engine (gated in benchmarks/serving_router.py).
+    engine (tests/test_router.py).
 
 Backpressure instead of floating state: a completed prefill stays
 resident on the prefill worker (slot + blocks held) until

@@ -19,7 +19,7 @@ bit-identical to a single-engine oracle on the same request set
 regardless of placement: per-slot sampling is keyed by (seed, rid,
 token index) and cache rows depend only on their token prefix, so
 *which* replica serves a request can never change its tokens (tested
-in tests/test_router.py, gated in benchmarks/serving_router.py).
+in tests/test_router.py).
 
 Each replica is either **fused** (``FusedReplica``: one engine does
 prefill and decode, admission runs every prefill chunk inline — the
@@ -32,8 +32,7 @@ deployment runs them concurrently; a single host process necessarily
 steps them in sequence. The router therefore tracks both
 ``serial_time`` (what this process spent) and ``modeled_time``
 (sum over router steps of the slowest replica's busy time that step —
-the deployment's critical path). ``benchmarks/serving_router.py``
-gates throughput scaling on the modeled number and says so.
+the deployment's critical path).
 """
 from __future__ import annotations
 

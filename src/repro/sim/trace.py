@@ -17,9 +17,9 @@ tensor snapshots, nothing on the engine's jit path.
 
 Synthetic workloads (`reference_vit_operands`, `synthetic_workload`)
 pin the paper's evaluation points: the ViT-style N=197, D=64 scores
-matrix with a padded tail — shared by examples/cim_macro_sim.py,
-benchmarks/sim_trace.py and tests so the ">=55% skip / 34.1 TOPS/W"
-reference is defined exactly once.
+matrix with a padded tail — shared by examples/cim_macro_sim.py and
+tests/test_sim.py so the ">=55% skip / 34.1 TOPS/W" reference is
+defined exactly once.
 """
 from __future__ import annotations
 

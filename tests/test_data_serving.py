@@ -52,6 +52,17 @@ def test_unpacked_padding_stats():
     assert np.all(b["labels"][b["loss_mask"] == 0] == tokenizer.PAD_ID)
 
 
+def test_packing_removes_padding():
+    """Sequence packing is the token-level zero-skip: the same documents
+    that leave an unpacked batch padded fill a packed one to < 2%."""
+    kw = dict(vocab_size=50000, seq_len=512, global_batch=16)
+    unpacked = pipeline.make_batch(
+        pipeline.DataConfig(pack=False, mean_doc_len=160, **kw), 0)
+    packed = pipeline.make_batch(pipeline.DataConfig(pack=True, **kw), 0)
+    assert pipeline.pad_fraction(packed) < 0.02 < \
+        pipeline.pad_fraction(unpacked)
+
+
 @settings(max_examples=20, deadline=None)
 @given(st.text(min_size=0, max_size=60))
 def test_tokenizer_roundtrip(s):

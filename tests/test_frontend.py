@@ -375,6 +375,27 @@ def test_radix_fork_from_finished_request(setup):
     assert r.output == o2
 
 
+def test_radix_hit_rate_on_sequential_shared_prefix_trace(setup):
+    """Sixteen requests share a 4-block system prompt and arrive one
+    after another, each finished before the next is submitted, so only
+    the radix tree's pinned blocks can serve the prefix: at least half
+    the offered blocks hit."""
+    eng = _engine(setup, radix_cache=True)
+    rng = np.random.default_rng(2)
+    prefix = [1] + rng.integers(3, 500, 4 * 8 - 1).tolist()
+
+    async def go():
+        async with AsyncEngine(eng) as srv:
+            for i in range(16):
+                tail = rng.integers(3, 500, 3).tolist()
+                await srv.submit(Request(rid=i, tokens=prefix + tail,
+                                         max_new_tokens=4)).collect()
+        return srv.metrics.snapshot(eng)
+
+    radix = asyncio.run(go())["radix"]
+    assert radix["hit_rate"] >= 0.5, radix
+
+
 def test_radix_evicts_under_allocator_pressure(setup):
     """Pinned historical blocks must yield (LRU) when admission needs
     the pool: a disjoint-prefix request still gets served."""

@@ -4,6 +4,7 @@ eviction, allocator exhaustion, copy-on-write prefix sharing)."""
 import dataclasses
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -95,6 +96,13 @@ def _mk_model(**over):
     return model, model.init(jax.random.PRNGKey(0))
 
 
+# one config per decode-cache layout: standard scores vs the paper's
+# folded W_QK with the xv or x-only cache
+_LAYOUTS = {"kv": {"score_mode": "standard"},
+            "xv": {"score_mode": "wqk", "cache_mode": "xv"},
+            "x": {"score_mode": "wqk", "cache_mode": "x"}}
+
+
 @pytest.fixture(scope="module")
 def setup():
     return _mk_model()
@@ -127,16 +135,99 @@ def test_paged_engine_matches_dense(score_mode):
         assert x.output == y.output, (x.rid, x.output, y.output)
 
 
-@pytest.mark.parametrize("schedule", ["gather", "stream"])
-def test_paged_logits_match_dense(setup, schedule):
+@pytest.mark.parametrize("layout", list(_LAYOUTS))
+def test_paged_admits_twice_dense_concurrency_at_equal_hbm(layout):
+    """At the HBM of four worst-case dense sequences, the paged pool
+    admits at least twice the dense engine's peak concurrency on mixed
+    prompt lengths, for every cache layout: dense reserves max_len per
+    slot, paged only the blocks a sequence fills."""
+    model, params = _mk_model(**_LAYOUTS[layout])
+    max_len, block = 128, 8
+    budget = kvcache.budget_for(model.cfg)
+    hbm = 4 * max_len * budget.bytes_per_token
+    dense = Engine(model, params, paged=False, max_len=max_len,
+                   max_slots=max(1, int(budget.max_tokens(hbm)) // max_len))
+    pagede = Engine(model, params, max_slots=16, max_len=max_len,
+                    paged=True, block_size=block, prefill_chunk=2 * block,
+                    num_blocks=kvcache.paged_budget_for(
+                        model.cfg, block).max_blocks(hbm))
+    plens = (4, 9, 17, 26, 33, 40)
+    ra, rb = _reqs(24, max_new=8, plens=plens), _reqs(24, max_new=8,
+                                                      plens=plens)
+    dense.run(ra)
+    pagede.run(rb)
+    assert all(r.done for r in ra + rb)
+    assert [r.output for r in ra] == [r.output for r in rb]
+    assert pagede.peak_active >= 2 * dense.peak_active, \
+        (dense.peak_active, pagede.peak_active)
+
+
+def paged_vs_dense_logits(model, params, prompt, *, max_len, block_size,
+                          chunk, steps, schedule="gather"):
+    """Greedy per-token logits from the dense prefill+decode path vs the
+    paged chunked-prefill+decode graph on the same prompt. Returns
+    (ref, got): lists of numpy (vocab,) logit rows — the admission
+    logit plus ``steps`` decode steps each.
+
+    schedule: 'gather' runs the dense-view oracle schedule; 'stream'
+    passes per-step used-block counts so the block-streamed path (the
+    serving default) is what gets checked against the dense reference.
+    """
+    batch = {"tokens": jnp.asarray([prompt], jnp.int32),
+             "lengths": jnp.asarray([len(prompt)], jnp.int32)}
+    logits, cache = model.prefill(params, batch, max_len)
+    ref = [np.asarray(logits[0])]
+    tok, pos = int(jnp.argmax(logits, -1)[0]), len(prompt)
+    for _ in range(steps):
+        logits, cache = model.decode_step(
+            params, cache, jnp.asarray([tok], jnp.int32),
+            jnp.asarray([pos], jnp.int32))
+        ref.append(np.asarray(logits[0]))
+        tok, pos = int(jnp.argmax(logits, -1)[0]), pos + 1
+
+    nbk = blocks_for(max_len, block_size)
+    pool = model.init_paged_cache(num_blocks=nbk + 1,
+                                  block_size=block_size)
+    nres = blocks_for(len(prompt) + steps + 1, block_size)
+    tables = np.zeros((1, nbk), np.int32)
+    tables[0, :nres] = range(1, 1 + nres)
+    tables = jnp.asarray(tables)
+
+    def used(last_pos):
+        if schedule != "stream":
+            return None
+        return jnp.asarray([min(last_pos // block_size + 1, nbk)],
+                           np.int32)
+
+    for c0 in range(0, len(prompt), chunk):
+        buf = np.zeros((1, chunk), np.int32)
+        piece = prompt[c0:c0 + chunk]
+        buf[0, :len(piece)] = piece
+        lg, pool = model.decode_paged(params, pool, tables,
+                                      jnp.asarray(buf),
+                                      jnp.asarray([c0], np.int32),
+                                      used(c0 + chunk - 1))
+    got = [np.asarray(lg[0, len(prompt) - 1 - c0])]
+    tok, pos = int(np.argmax(got[-1])), len(prompt)
+    for _ in range(steps):
+        lg, pool = model.decode_paged(
+            params, pool, tables, jnp.asarray([[tok]], jnp.int32),
+            jnp.asarray([pos], np.int32), used(pos))
+        got.append(np.asarray(lg[0, 0]))
+        tok, pos = int(np.argmax(got[-1])), pos + 1
+    return ref, got
+
+
+@pytest.mark.parametrize("layout,schedule", [
+    pytest.param(lay, sched, id=sched if lay == "kv" else f"{lay}-{sched}")
+    for lay in _LAYOUTS for sched in ("gather", "stream")])
+def test_paged_logits_match_dense(layout, schedule):
     """Per-token logits through the paged graph match the dense
     prefill+decode path to fp tolerance (incl. a chunk-crossing
-    prompt) — on BOTH decode schedules: the dense gather view and the
-    block-streamed early-exit path. Runs the same harness as the CI
-    serving acceptance check (benchmarks.serving_load) so the two
-    cannot drift apart."""
-    from benchmarks.serving_load import paged_vs_dense_logits
-    model, params = setup
+    prompt) — for every cache layout (kv / xv / x) on BOTH decode
+    schedules: the dense gather view and the block-streamed early-exit
+    path."""
+    model, params = _mk_model(**_LAYOUTS[layout])
     prompt = [1] + list(range(5, 22))            # 18 tokens, chunks of 8
     ref, got = paged_vs_dense_logits(model, params, prompt, max_len=48,
                                      block_size=4, chunk=8, steps=4,

@@ -199,6 +199,26 @@ def test_memory_bytes_per_token_matches_budget():
             pl.backend.memory_bytes_per_token(cfg, 2, cache_mode=pl.cache_mode)
 
 
+@pytest.mark.parametrize("name", sorted(set(sb.list_backends())
+                                         - {"standard"}))
+def test_x_cache_backends_beat_kv_bytes_on_whisper(name):
+    """On whisper geometry (D=384 < 2*Hkv*dh=768) the planner gives
+    every backend but standard the pure X-cache, which stores fewer bytes
+    per token than the standard backend's K/V cache."""
+    cfg = get_arch("whisper-tiny")
+
+    def bytes_per_token(backend):
+        pl = sb.plan(dataclasses.replace(cfg, score_mode=backend,
+                                         cache_mode=None))
+        return pl.cache_mode, sb.get_backend(backend).memory_bytes_per_token(
+            cfg, cache_mode=pl.cache_mode)
+
+    kv_mode, kv = bytes_per_token("standard")
+    mode, got = bytes_per_token(name)
+    assert (kv_mode, mode) == ("kv", "x")
+    assert got < kv, (name, got, kv)
+
+
 def test_deprecated_shim_removed():
     """The stringly-typed compute_scores shim and the SCORE_MODES static
     snapshot were removed this release; the registry is canonical."""
